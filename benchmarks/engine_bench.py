@@ -125,17 +125,25 @@ def _bench_once(engine_mod, sched_name: str, n_nodes: int, n_instances: int,
     return rec
 
 
-def _kmeans_fleet_probe(n_profiles: int) -> dict:
-    """choose_k at fleet scale: 10^5 synthetic profiles through the
-    segment-sum Lloyd path and the blocked/sampled silhouette — no (n, n)
-    (or even (sample, sample)) distance matrix is ever materialized."""
+def fleet_profiles(n_profiles: int):
+    """[n, 3] synthetic node profiles (cpu, mem bandwidth, io) of a fleet in
+    the paper's three tiers, 1 % noise per feature (0.3 % on the shared io)."""
     import numpy as np
-    from repro.core.clustering import choose_k
     rng = np.random.default_rng(0)
     centers = np.array([[375.0, 14050.0], [463.0, 17600.0], [524.0, 19850.0]])
     tier = rng.integers(0, 3, n_profiles)
-    X = np.c_[centers[tier] * (1.0 + rng.normal(0, 0.01, (n_profiles, 2))),
-              np.full((n_profiles, 1), 482.0) * (1.0 + rng.normal(0, 0.003, (n_profiles, 1)))]
+    return np.c_[centers[tier] * (1.0 + rng.normal(0, 0.01, (n_profiles, 2))),
+                 np.full((n_profiles, 1), 482.0)
+                 * (1.0 + rng.normal(0, 0.003, (n_profiles, 1)))]
+
+
+def _kmeans_fleet_probe(n_profiles: int) -> dict:
+    """choose_k at fleet scale: 10^5 synthetic profiles through the
+    Lloyd step (the Pallas kernel on TPU, segment sums elsewhere) and the
+    blocked/sampled silhouette — no (n, n) (or even (sample, sample))
+    distance matrix is ever materialized."""
+    from repro.core.clustering import choose_k
+    X = fleet_profiles(n_profiles)
     t0 = time.perf_counter()
     res = choose_k(X, k_max=4, restarts=2)
     wall = time.perf_counter() - t0

@@ -116,7 +116,9 @@ def driver_spec(workdir: str, wf: WorkflowSpec, spin_ms: float,
 
 
 def run_driver(spec: dict, timeout: float = 120.0):
-    env = dict(os.environ)
+    # the driver runs a CPU control plane; on an accelerator host it must
+    # not reach for a chip that this (or another) process holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     pp = env.get("PYTHONPATH", "")

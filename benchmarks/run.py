@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import sys
 import time
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
@@ -23,6 +24,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (engine_bench, ensemble_bench, faults_bench,
                             fig3_workflow_profiles, fig45_runtimes,
                             fig67_usage, fig8_multiworkflow, kernel_bench,
@@ -49,6 +52,7 @@ def main() -> None:
     }
     os.makedirs(RESULTS, exist_ok=True)
     all_out = {}
+    failed = []
     for name, fn in suites.items():
         if args.only and name != args.only:
             continue
@@ -60,6 +64,7 @@ def main() -> None:
         except Exception as e:  # pragma: no cover
             print(f"# suite {name} FAILED: {type(e).__name__}: {e}\n")
             all_out[name] = {"error": str(e)}
+            failed.append(name)
 
     def _clean(o):
         if isinstance(o, dict):
@@ -73,6 +78,8 @@ def main() -> None:
     with open(os.path.join(RESULTS, "bench_summary.json"), "w") as f:
         json.dump(_clean(all_out), f, indent=1)
     print("# wrote", os.path.join(RESULTS, "bench_summary.json"))
+    if failed:
+        sys.exit(f"# suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

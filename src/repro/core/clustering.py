@@ -4,10 +4,10 @@ Pure JAX, jit-able, deterministic in the PRNG key.  This is the fleet-scale
 path: on 15-node clusters it is instant, and the same code groups 10^5
 profiles:
 
-  * the Lloyd update uses a segment-sum (or, on TPU, the fused
-    ``repro.kernels.kmeans.kmeans_lloyd_step`` Pallas kernel that emits
-    labels and per-cluster sums/counts in one pass) instead of the seed's
-    (n, k) one-hot matmul;
+  * the Lloyd update uses a segment-sum (or, on TPU and at any point
+    count, the fused ``repro.kernels.kmeans.kmeans_lloyd_step`` Pallas
+    kernel that emits labels and per-cluster sums/counts in one pass)
+    instead of the seed's (n, k) one-hot matmul;
   * ``silhouette_blocked`` streams row blocks so the dense (n, n) distance
     matrix never exists; ``choose_k`` scores large inputs on a
     deterministic subsample through that blocked path.
@@ -48,17 +48,26 @@ def _pairwise_sq(X, C):
     return jnp.maximum(x2 + c2 - 2.0 * X @ C.T, 0.0)
 
 
+def uses_lloyd_kernel(X) -> bool:
+    """Whether ``kmeans_pp`` runs its Lloyd steps through the fused Pallas
+    kernel: yes where the points live on a TPU, at any point count.  The
+    platform is read from the array itself, so a call under
+    ``jax.default_device(<cpu device>)`` on a TPU host takes the CPU path."""
+    X = jnp.asarray(X)
+    return all(d.platform == "tpu" for d in X.devices())
+
+
 def kmeans_pp(X, k: int, key, iters: int = 32, use_kernel: bool | None = None):
     """Returns (labels (n,), centers (k,f), inertia scalar).
 
-    ``use_kernel=None`` auto-selects the fused Pallas Lloyd step on TPU
-    (when the point count tiles evenly); the portable path computes the
-    update with segment-sums, so neither path materializes the (n, k)
-    one-hot matmul of the seed implementation.
+    ``use_kernel=None`` takes the fused Pallas Lloyd step on TPU (see
+    ``uses_lloyd_kernel``); the portable path computes the update with
+    segment-sums, so neither path materializes the (n, k) one-hot matmul
+    of the seed implementation.
     """
+    X = jnp.asarray(X)
     if use_kernel is None:
-        use_kernel = (jax.default_backend() == "tpu"
-                      and X.shape[0] % 1024 == 0)
+        use_kernel = uses_lloyd_kernel(X)
     return _kmeans_pp(X, k, key, iters, bool(use_kernel))
 
 
@@ -86,9 +95,8 @@ def _kmeans_pp(X, k: int, key, iters: int, use_kernel: bool):
     def lloyd(carry, _):
         C, _ = carry
         if use_kernel:
-            from repro.kernels.kmeans import kmeans_lloyd_step
-            lab, _d, sums, counts = kmeans_lloyd_step(
-                X, C, block_n=min(1024, n))
+            from repro.kernels.ops import kmeans_lloyd_step
+            lab, _d, sums, counts = kmeans_lloyd_step(X, C)
             sums = sums.astype(X.dtype)
             counts = counts.astype(X.dtype)
         else:

@@ -10,10 +10,18 @@ Everything is plain ``jax.numpy``: on this CPU-only container a Pallas
 lowering would force interpret mode (slower than XLA:CPU's fused
 elementwise loops), and the shapes involved — [R, N] node panels and
 [R, T] task panels — are bandwidth-, not compute-, bound.  Bit-for-bit
-equivalence with the numpy engine is part of the contract: every
-expression mirrors its engine twin operand-for-operand (same multiply /
-divide nesting), so under ``jax.experimental.enable_x64`` the scan's f64
-results are identical to the sequential engine's.
+equivalence with the numpy engine is part of the contract on XLA:CPU:
+every expression mirrors its engine twin operand-for-operand (same
+multiply / divide nesting), so under ``jax.enable_x64(True)`` the scan's
+f64 results are identical to the sequential engine's.
+
+On a TPU, f64 is emulated in pairs of f32 and is not IEEE binary64: on a
+TPU v5 lite a jitted f64 add, multiply and divide each differ from numpy
+in most lanes (largest relative errors about 7e-15, 1.5e-14 and 5e-14 on
+uniform random operands).  The scan built from these expressions still
+made every decision of the oracle (node assignment, finish order) at
+256 nodes x 2,000 instances x 64 replicas for fair and sjfn, with start
+and end times within 1.1e-13 relative (``ensemble.TPU_TIME_RTOL``).
 
 All helpers are batched over a leading replica axis R and are intended to
 be called from inside an already-jitted ``lax.scan`` step (they are not

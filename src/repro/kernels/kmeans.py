@@ -15,6 +15,10 @@ Two entry points:
   points, so the (n, k) one-hot never exists in HBM and the update step
   needs no second matmul over the full point set.
 
+Any point count works: the wrappers pad the rows to a multiple of the
+block, and the Lloyd kernel masks the padded rows out of the sums and
+counts; labels and distances come back for the real rows only.
+
 TARGET: TPU.  Validated via interpret=True vs ref.kmeans_assign /
 ref.kmeans_lloyd_step in tests.
 """
@@ -38,27 +42,37 @@ def _assign_kernel(x_ref, c_ref, lab_ref, dist_ref):
     dist_ref[...] = jnp.min(d, axis=1)
 
 
+def _pad_rows(x, block_n: int):
+    """(x padded with zero rows to a multiple of the block, block size)."""
+    n = x.shape[0]
+    block_n = min(block_n, n)
+    pad = -n % block_n
+    return jnp.pad(x, ((0, pad), (0, 0))), block_n
+
+
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def kmeans_assign(x, c, *, block_n: int = 1024, interpret: bool = False):
     """x: (N, f); c: (k, f) -> (labels (N,) int32, sq-dists (N,) f32)."""
-    N, f = x.shape
+    N = x.shape[0]
+    xp, block_n = _pad_rows(x, block_n)
+    Np, f = xp.shape
     k = c.shape[0]
-    block_n = min(block_n, N)
-    assert N % block_n == 0
-    return pl.pallas_call(
+    lab, dist = pl.pallas_call(
         _assign_kernel,
-        grid=(N // block_n,),
+        grid=(Np // block_n,),
         in_specs=[pl.BlockSpec((block_n, f), lambda i: (i, 0)),
                   pl.BlockSpec((k, f), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((block_n,), lambda i: (i,)),
                    pl.BlockSpec((block_n,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32),
-                   jax.ShapeDtypeStruct((N,), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((Np,), jnp.int32),
+                   jax.ShapeDtypeStruct((Np,), jnp.float32)],
         interpret=interpret,
-    )(x, c)
+    )(xp, c)
+    return lab[:N], dist[:N]
 
 
-def _lloyd_kernel(x_ref, c_ref, lab_ref, dist_ref, sums_ref, cnt_ref):
+def _lloyd_kernel(x_ref, c_ref, lab_ref, dist_ref, sums_ref, cnt_ref, *,
+                  n_valid: int):
     i = pl.program_id(0)
     x = x_ref[...].astype(jnp.float32)               # (block_n, f)
     c = c_ref[...].astype(jnp.float32)               # (k, f)
@@ -72,8 +86,13 @@ def _lloyd_kernel(x_ref, c_ref, lab_ref, dist_ref, sums_ref, cnt_ref):
     dist_ref[...] = jnp.min(d, axis=1)
     # block-local one-hot lives only in VMEM; contraction over the block
     # dimension yields this block's per-cluster sums/counts on the MXU
-    onehot = (lab[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-              ).astype(jnp.float32)                  # (block_n, k)
+    onehot = lab[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    block_n = x.shape[0]
+    if n_valid % block_n:
+        # padded rows (global index >= n_valid) join no cluster
+        row = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)
+        onehot = onehot & (row < n_valid)
+    onehot = onehot.astype(jnp.float32)              # (block_n, k)
     block_sums = jax.lax.dot_general(
         onehot, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (k, f)
@@ -99,22 +118,23 @@ def kmeans_lloyd_step(x, c, *, block_n: int = 1024, interpret: bool = False):
     counts (k,) f32) — everything the update `c' = sums / counts` and the
     inertia `sum(sq-dists)` need, from a single pass over the points.
     """
-    N, f = x.shape
+    N = x.shape[0]
+    xp, block_n = _pad_rows(x, block_n)
+    Np, f = xp.shape
     k = c.shape[0]
-    block_n = min(block_n, N)
-    assert N % block_n == 0
-    return pl.pallas_call(
-        _lloyd_kernel,
-        grid=(N // block_n,),
+    lab, dist, sums, cnt = pl.pallas_call(
+        functools.partial(_lloyd_kernel, n_valid=N),
+        grid=(Np // block_n,),
         in_specs=[pl.BlockSpec((block_n, f), lambda i: (i, 0)),
                   pl.BlockSpec((k, f), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec((block_n,), lambda i: (i,)),
                    pl.BlockSpec((block_n,), lambda i: (i,)),
                    pl.BlockSpec((k, f), lambda i: (0, 0)),
                    pl.BlockSpec((k,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32),
-                   jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((Np,), jnp.int32),
+                   jax.ShapeDtypeStruct((Np,), jnp.float32),
                    jax.ShapeDtypeStruct((k, f), jnp.float32),
                    jax.ShapeDtypeStruct((k,), jnp.float32)],
         interpret=interpret,
-    )(x, c)
+    )(xp, c)
+    return lab[:N], dist[:N], sums, cnt

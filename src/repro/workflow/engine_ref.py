@@ -123,7 +123,11 @@ class Engine:
 
     def _time_left(self, task: TaskInstance) -> float:
         rates = self._rates(task)
-        return sum(task.remaining[f] / rates[f] for f in ("cpu", "mem", "io"))
+        rem = task.remaining
+        # plain left-to-right adds: from Python 3.12 on, sum() of floats is
+        # compensated and would no longer give the seed's values
+        return rem["cpu"] / rates["cpu"] + rem["mem"] / rates["mem"] \
+            + rem["io"] / rates["io"]
 
     def _feasible(self, task: TaskInstance) -> dict:
         feas = {n.name: (not n.disabled and n.free_cores >= task.req_cores

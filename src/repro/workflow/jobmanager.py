@@ -225,6 +225,10 @@ class LocalProcessBackend(ExecutionBackend):
         self.enforce_requests = enforce_requests
         self.sample_interval_s = sample_interval_s
         self._env = dict(os.environ if env is None else env)
+        # children run on virtual CPU nodes; a child that reached for the
+        # accelerator would fail or hang on the lock of a parent that holds
+        # it (a chip belongs to one process)
+        self._env["JAX_PLATFORMS"] = "cpu"
         pp = self._env.get("PYTHONPATH", "")
         if _SRC_ROOT not in pp.split(os.pathsep):
             self._env["PYTHONPATH"] = (_SRC_ROOT + os.pathsep + pp) if pp \

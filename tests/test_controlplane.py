@@ -400,3 +400,14 @@ def test_selfhost_workflow_shape():
     assert "train" in [t.name for t in wf_t.tasks]
     report = next(t for t in wf_t.tasks if t.name == "report")
     assert "train" in report.deps
+
+
+@pytest.mark.parametrize("caller_env", [{}, {"JAX_PLATFORMS": "tpu"}])
+def test_local_backend_children_stay_off_the_accelerator(tmp_path,
+                                                         caller_env):
+    """Task children run on virtual CPU nodes: whatever the caller's env
+    says, they get ``JAX_PLATFORMS=cpu``, so none reaches for a chip the
+    parent process holds."""
+    be = LocalProcessBackend(two_local_nodes(tmp_path),
+                             runner=probe_runner(), env=dict(caller_env))
+    assert be._env["JAX_PLATFORMS"] == "cpu"

@@ -23,7 +23,8 @@ from repro.workflow.cluster import cluster_555, cluster_5442
 from repro.workflow.dag import AbstractTask, WorkflowSpec
 from repro.workflow.engine import Engine, EngineConfig
 from repro.workflow.ensemble import (Submission, assert_equivalent,
-                                     oracle_ensemble, run_ensemble)
+                                     compare_traces, oracle_ensemble,
+                                     run_ensemble)
 from repro.workflow.faults import FaultConfig
 from repro.workflow.nfcore import WORKFLOWS
 
@@ -111,6 +112,29 @@ def test_scan_replica_seeds_match_individual_engine_runs():
         eng.submit(wf, run_id=0, seed=3 + 10 * r)
         out = eng.run()
         assert out["makespan"] == res.makespan[r]
+
+
+def test_compare_traces_separates_decisions_from_times():
+    """The TPU contract's measure: decisions exact, times to a tolerance."""
+    import dataclasses
+    specs = cluster_555()
+    subs = [Submission(WORKFLOWS["mag"](), seed=3)]
+    ref = oracle_ensemble(specs, subs, make_scheduler("fair", specs, seed=0),
+                          n_replicas=2)
+    same = compare_traces(ref, ref)
+    assert same["decisions_equal"] and same["bitwise"]
+    assert same["max_rel_err"] == 0.0 and same["first_divergence"] is None
+    drift = dataclasses.replace(ref, end_t=ref.end_t * (1.0 + 1e-12),
+                                makespan=ref.makespan * (1.0 + 1e-12))
+    out = compare_traces(drift, ref)
+    assert out["decisions_equal"] and not out["bitwise"]
+    assert 0.5e-12 < out["max_rel_err"] < 2e-12
+    order = ref.finish_order.copy()
+    order[1, [3, 4]] = order[1, [4, 3]]
+    out = compare_traces(dataclasses.replace(ref, finish_order=order), ref)
+    assert not out["decisions_equal"]
+    assert out["first_divergence"]["replica"] == 1
+    assert out["first_divergence"]["finish_position"] == 3
 
 
 # ------------------------------------------------------- loud refusals

@@ -121,12 +121,11 @@ def test_kmeans_lloyd_step_multiblock_accumulation():
 
 # ------------------------------------------------- ensemble scan helpers
 # numpy mirrors of the engine expressions these kernels must match
-# bit-for-bit (f64 under enable_x64 inside the ensemble scan; here the
+# bit-for-bit (f64 under jax.enable_x64 inside the ensemble scan; here the
 # comparison runs in f64 numpy on both sides).
 
 
 def test_ensemble_node_rates_matches_engine_math():
-    from jax.experimental import enable_x64
     from repro.kernels import ensemble_step as ks
     rng = np.random.default_rng(0)
     R, N = 4, 7
@@ -140,7 +139,7 @@ def test_ensemble_node_rates_matches_engine_math():
     occ = 1.0 - free / cores
     want_cpu = cpu_base * (1.0 - smt * np.maximum(0.0, occ - 0.5) / 0.5)
     want_mem = mem_base / mem_denom
-    with enable_x64():
+    with jax.enable_x64(True):
         cpu, mem = ks.node_rates(jnp.asarray(free), jnp.asarray(mem_denom),
                                  jnp.asarray(cpu_base), jnp.asarray(mem_base),
                                  jnp.asarray(cores), smt)
@@ -149,7 +148,6 @@ def test_ensemble_node_rates_matches_engine_math():
 
 
 def test_ensemble_time_left_and_advance_match_numpy():
-    from jax.experimental import enable_x64
     from repro.kernels import ensemble_step as ks
     rng = np.random.default_rng(1)
     R, N, C = 3, 4, 2
@@ -158,7 +156,7 @@ def test_ensemble_time_left_and_advance_match_numpy():
     want_tl = sum(r / s[:, :, None] for r, s in zip(rem, rates))
     dt = rng.uniform(0, 5, R)
     scale = 1.0 - np.minimum(dt[:, None, None] / want_tl, 1.0)
-    with enable_x64():
+    with jax.enable_x64(True):
         tl = ks.time_left(*[jnp.asarray(r) for r in rem],
                           *[jnp.asarray(s) for s in rates])
         np.testing.assert_array_equal(np.asarray(tl), want_tl)

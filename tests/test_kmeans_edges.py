@@ -143,3 +143,55 @@ def test_choose_k_three_profiles_sweeps_k2_only():
     res = choose_k(X, k_max=6)
     assert res["k"] == 2
     assert list(res["per_k"]) == [2]
+
+
+# ------------------------------------------ point counts off the block size
+def _blobs(n, f, seed):
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(0.0, 5.0, (3, f))
+    return jnp.asarray(centers[rng.integers(0, 3, n)]
+                       + rng.normal(0.0, 0.3, (n, f)), jnp.float32)
+
+
+@pytest.mark.parametrize("n,block_n", [(1500, 1024), (5000, 1024),
+                                       (777, 256)])
+def test_lloyd_kernel_masks_padded_rows(n, block_n):
+    """n not a multiple of the block: the rows are padded, and the padded
+    rows add nothing to any sum or count (interpret mode vs the oracle)."""
+    x = _blobs(n, 3, n)
+    c = x[:4] + 0.1
+    lab_k, d_k, sums_k, cnt_k = kmeans_lloyd_step(x, c, block_n=block_n,
+                                                  interpret=True)
+    lab_r, d_r, sums_r, cnt_r = ref.kmeans_lloyd_step(x, c)
+    assert lab_k.shape == (n,) and d_k.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(lab_k), np.asarray(lab_r))
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(cnt_k), np.asarray(cnt_r))
+    assert float(jnp.sum(cnt_k)) == n
+    np.testing.assert_allclose(np.asarray(sums_k), np.asarray(sums_r),
+                               rtol=1e-5, atol=1e-3)
+
+
+def test_assign_kernel_pads_rows():
+    from repro.kernels.kmeans import kmeans_assign
+    x = _blobs(2500, 3, 5)
+    c = x[:3] + 0.1
+    lab_k, d_k = kmeans_assign(x, c, block_n=1024, interpret=True)
+    lab_r, d_r = ref.kmeans_assign(x, c)
+    assert lab_k.shape == (2500,)
+    np.testing.assert_array_equal(np.asarray(lab_k), np.asarray(lab_r))
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_kmeans_pp_kernel_path_at_any_point_count():
+    """The kernel path (interpret mode here) takes a point count that is
+    not a multiple of 1024 and groups exactly like the segment-sum path."""
+    X = standardize(np.asarray(_blobs(2500, 3, 9)) + 20.0)
+    key = jax.random.key(4)
+    lab_k, C_k, _ = kmeans_pp(X, 3, key, use_kernel=True)
+    lab_s, C_s, _ = kmeans_pp(X, 3, key, use_kernel=False)
+    np.testing.assert_array_equal(np.asarray(lab_k), np.asarray(lab_s))
+    np.testing.assert_allclose(np.asarray(C_k), np.asarray(C_s),
+                               rtol=1e-4, atol=1e-5)
