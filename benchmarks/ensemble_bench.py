@@ -6,7 +6,7 @@ saturated stage chain) through ``repro.workflow.ensemble`` — one jitted
 sequential numpy ``Engine`` oracle.  Emits
 ``benchmarks/results/BENCH_ensemble.json`` with two result families:
 
-* **throughput** — replicas/sec for the jitted program (steady-state,
+* **throughput** — replicas/sec for the jitted program (its one run,
   compile excluded; best of ``repeats`` launches) vs the sequential numpy
   loop, and their ratio.  The full-mode ratio gates the ROADMAP >= 10x
   floor.
@@ -81,12 +81,12 @@ def _bench_one(sched_name: str, n_nodes: int, n_instances: int,
     best_run, compile_s, build_s = math.inf, 0.0, 0.0
     for _ in range(repeats):
         # each launch rebuilds + recompiles (fresh closure); throughput
-        # reads the steady-state rerun that run_ensemble times separately
+        # reads the run, which run_ensemble times apart from the compile
         out = run_ensemble(specs, subs, make_scheduler(sched_name, specs,
                                                        seed=0), n_replicas)
         if out.timings["run_s"] < best_run:
             best_run = out.timings["run_s"]
-            compile_s = out.timings["compile_run_s"]
+            compile_s = out.timings["compile_s"]
             build_s = out.timings["build_s"]
         res = out
 

@@ -113,7 +113,7 @@ def phase_scan(cpu):
             f"{name}: decisions_equal={cmp['decisions_equal']} "
             f"bitwise={cmp['bitwise']} max_rel_err={cmp['max_rel_err']!r} "
             f"first_divergence={cmp['first_divergence']} "
-            f"compile_run_s={t['compile_run_s']!r} run_s={t['run_s']!r}")
+            f"compile_s={t['compile_s']!r} run_s={t['run_s']!r}")
     return ok, (f"{n_nodes}x{n_instances}x{n_replicas} "
                 f"rtol={ensemble.TPU_TIME_RTOL!r}; " + "; ".join(parts))
 

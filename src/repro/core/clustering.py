@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 
 def standardize(X, mode: str = "relative"):
     """Feature scaling before clustering.
@@ -175,24 +177,36 @@ def silhouette_blocked(X, labels, k: int, block: int = 1024):
 def choose_k(X, k_max: int = 6, key=None, restarts: int = 4,
              silhouette_sample: int = 4096, silhouette_block: int = 1024):
     """Sweep k in [2, k_max], pick max silhouette (paper's control function).
-    Returns dict(k, labels (np), centers, silhouette, per_k scores).
+    Returns dict(k, labels (np), centers, silhouette, per_k scores, timings).
 
     Paper-sized inputs (n <= silhouette_sample) keep the seed's dense
     scoring path bit-for-bit.  Above that, scores come from a
     deterministic subsample evaluated through ``silhouette_blocked``, so a
     10^5-profile sweep completes without an (n, n) — or even
     (sample, sample) — distance matrix.
+
+    ``timings`` is the call's ``tracing.Record``: the seconds of the spans
+    ``grouping.standardize`` (upload and scaling of ``X``),
+    ``grouping.kmeans`` (one per k and restart, its inertia read
+    included), ``grouping.silhouette`` (one per k) and ``grouping.sync``
+    (each blocking device-to-host read) as ``standardize_s``, ``kmeans_s``,
+    ``silhouette_s`` and ``sync_s``; ``kmeans_runs`` and ``host_syncs``
+    count the k-means runs and the blocking reads.
     """
-    X = standardize(X)
+    rec = tracing.Record()
+    sync = functools.partial(rec.span, "grouping.sync", count="host_syncs")
+    with rec.span("grouping.standardize"):
+        X = standardize(X)
     n = X.shape[0]
     if n < 3:
         # degenerate profile sets (the k sweep needs 2 <= k <= n-1): a
         # single node is its own group; two nodes get one group each —
         # silhouette is undefined either way, reported as 0.0
         labels = np.arange(n, dtype=np.int32)
-        return {"k": max(n, 1), "labels": labels,
-                "centers": np.asarray(X, np.float64), "silhouette": 0.0,
-                "per_k": {}}
+        with sync():
+            centers = np.asarray(X, np.float64)
+        return {"k": max(n, 1), "labels": labels, "centers": centers,
+                "silhouette": 0.0, "per_k": {}, "timings": rec.as_dict()}
     key = key if key is not None else jax.random.key(0)
     sample_idx = None
     if n > silhouette_sample:
@@ -204,18 +218,30 @@ def choose_k(X, k_max: int = 6, key=None, restarts: int = 4,
         best_k = None
         for r in range(restarts):
             sub = jax.random.fold_in(jax.random.fold_in(key, k), r)
-            labels, C, inertia = kmeans_pp(X, k, sub)
-            if best_k is None or float(inertia) < best_k[2]:
-                best_k = (labels, C, float(inertia))
+            with rec.span("grouping.kmeans", count="kmeans_runs"):
+                labels, C, inertia = kmeans_pp(X, k, sub)
+                with sync():
+                    inertia = float(inertia)
+            if best_k is None or inertia < best_k[2]:
+                best_k = (labels, C, inertia)
         labels, C, _ = best_k
-        if sample_idx is None:
-            score = float(silhouette(X, labels, k))
-        else:
-            score = float(silhouette_blocked(
-                X[sample_idx], labels[sample_idx], k, block=silhouette_block))
+        with rec.span("grouping.silhouette"):
+            if sample_idx is None:
+                score = silhouette(X, labels, k)
+            else:
+                score = silhouette_blocked(
+                    X[sample_idx], labels[sample_idx], k,
+                    block=silhouette_block)
+            with sync():
+                score = float(score)
         per_k[k] = score
         if best is None or score > best["silhouette"]:
-            best = {"k": k, "labels": np.asarray(labels), "centers": np.asarray(C),
+            with sync():
+                labels = np.asarray(labels)
+            with sync():
+                C = np.asarray(C)
+            best = {"k": k, "labels": labels, "centers": C,
                     "silhouette": score}
     best["per_k"] = per_k
+    best["timings"] = rec.as_dict()
     return best

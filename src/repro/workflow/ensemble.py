@@ -69,6 +69,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.monitor import TraceDB
 from repro.core.scheduler import (FairScheduler, FillNodesScheduler,
                                   RoundRobinScheduler, SJFNScheduler)
@@ -772,38 +773,46 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
     supported feature matrix and the RNG-stream mapping; unsupported
     configurations raise ``NotImplementedError`` at build time.
 
-    The program runs twice — first invocation compiles — and ``timings``
-    splits build / compile+run / steady-state-rerun wall seconds so
-    throughput reads never credit compilation."""
+    The program is compiled and then run once.  ``timings`` is the call's
+    ``tracing.Record``: the seconds of the spans ``ensemble.build``
+    (topology, work draws, closure and uploads), ``ensemble.compile``
+    (lowering and compiling), ``ensemble.run`` (the run, to
+    ``block_until_ready``), ``ensemble.fetch`` (copies to the host and
+    the result) and ``ensemble.release`` (dropping the program and its
+    device buffers) as ``build_s``, ``compile_s``, ``run_s``, ``fetch_s``
+    and ``release_s``; ``compiles``, and the scan's ``n_steps``."""
     import jax
 
-    t0 = time.perf_counter()
-    top = _Topology(specs, submissions, scheduler, config, n_replicas,
-                    seed_stride)
+    rec = tracing.Record()
     with jax.enable_x64(True):
-        scan, args = _build_scan(top)
-        t1 = time.perf_counter()
-        out = jax.block_until_ready(scan(*args))
-        t2 = time.perf_counter()
-        out = jax.block_until_ready(scan(*args))
-        t3 = time.perf_counter()
+        with rec.span("ensemble.build"):
+            top = _Topology(specs, submissions, scheduler, config,
+                            n_replicas, seed_stride)
+            scan, args = _build_scan(top)
+        with rec.span("ensemble.compile", count="compiles"):
+            compiled = scan.lower(*args).compile()
+        with rec.span("ensemble.run"):
+            out = jax.block_until_ready(compiled(*args))
 
-    T = top.T
-    n_fin = np.asarray(out[16])
-    if not (n_fin == T).all():
-        raise RuntimeError(
-            f"ensemble scan under-ran: {int(n_fin.min())}/{T} finishes "
-            f"within {top.n_steps} steps — step budget bug")
-    end_t = np.asarray(out[19])[:, :T]
-    fstep = np.asarray(out[20])[:, :T]
-    return EnsembleResult(
-        instances=top.instances, makespan=end_t.max(axis=1),
-        node_idx=np.asarray(out[17])[:, :T].astype(np.int32),
-        start_t=np.asarray(out[18])[:, :T], end_t=end_t,
-        finish_order=np.argsort(fstep, axis=1,
-                                kind="stable").astype(np.int32),
-        timings={"build_s": t1 - t0, "compile_run_s": t2 - t1,
-                 "run_s": t3 - t2, "n_steps": top.n_steps})
+    with rec.span("ensemble.fetch"):
+        T = top.T
+        n_fin = np.asarray(out[16])
+        if not (n_fin == T).all():
+            raise RuntimeError(
+                f"ensemble scan under-ran: {int(n_fin.min())}/{T} finishes "
+                f"within {top.n_steps} steps — step budget bug")
+        end_t = np.asarray(out[19])[:, :T]
+        fstep = np.asarray(out[20])[:, :T]
+        res = EnsembleResult(
+            instances=top.instances, makespan=end_t.max(axis=1),
+            node_idx=np.asarray(out[17])[:, :T].astype(np.int32),
+            start_t=np.asarray(out[18])[:, :T], end_t=end_t,
+            finish_order=np.argsort(fstep, axis=1,
+                                    kind="stable").astype(np.int32))
+    with rec.span("ensemble.release"):
+        del scan, args, compiled, out
+    res.timings = {**rec.as_dict(), "n_steps": top.n_steps}
+    return res
 
 
 def oracle_ensemble(specs, submissions, scheduler, n_replicas, *,

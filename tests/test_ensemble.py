@@ -199,3 +199,66 @@ def test_degenerate_arguments_raise_value_error():
         run_ensemble(specs, [], sched, 1)
     with pytest.raises(ValueError):
         run_ensemble(specs, [Submission(_toy())], sched, 0)
+
+
+def test_run_ensemble_compiles_once_and_runs_once(monkeypatch):
+    """One lowering, one compile and one run of the scan per call; the
+    call's record holds the spans' seconds, the compile count and the step
+    count, and no timing of a second run."""
+    import jax
+
+    from repro.workflow import ensemble
+
+    calls = {"lower": 0, "jit_call": 0, "compiled_call": 0}
+
+    class Counted:
+        def __init__(self, fn):
+            self.fn = fn
+
+        def lower(self, *args):
+            calls["lower"] += 1
+            return self.fn.lower(*args)
+
+        def __call__(self, *args):
+            calls["jit_call"] += 1
+            return self.fn(*args)
+
+    build = ensemble._build_scan
+    monkeypatch.setattr(ensemble, "_build_scan",
+                        lambda top: (lambda s, a: (Counted(s), a))(*build(top)))
+    run_compiled = jax.stages.Compiled.__call__
+
+    def counted_call(self, *args, **kwargs):
+        calls["compiled_call"] += 1
+        return run_compiled(self, *args, **kwargs)
+
+    monkeypatch.setattr(jax.stages.Compiled, "__call__", counted_call)
+    specs = cluster_5442()
+    res = run_ensemble(specs, [Submission(_toy(), seed=3)],
+                       make_scheduler("fair", specs, seed=0), 2)
+    assert calls == {"lower": 1, "jit_call": 0, "compiled_call": 1}
+    t = res.timings
+    assert t["compiles"] == 1 and t["n_steps"] > 0
+    assert "compile_run_s" not in t
+    for key in ("build_s", "compile_s", "run_s", "fetch_s", "release_s"):
+        assert t[key] > 0.0
+    assert [(name, parent) for name, _, _, parent in t["spans"]] == [
+        ("ensemble.build", None), ("ensemble.compile", None),
+        ("ensemble.run", None), ("ensemble.fetch", None),
+        ("ensemble.release", None)]
+
+
+def test_scan_module_is_named_jit_scan():
+    """The device trace finds the scan's program by this name
+    (``bench/metrics/scan.step_us.py``)."""
+    import jax
+
+    from repro.workflow import ensemble
+
+    specs = _specs()
+    top = ensemble._Topology(specs, [Submission(_toy())],
+                             make_scheduler("fair", specs, seed=0), None, 1, 1)
+    with jax.enable_x64(True):
+        scan, args = ensemble._build_scan(top)
+        text = scan.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_scan,")

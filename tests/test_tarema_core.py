@@ -72,6 +72,40 @@ def test_choose_k_fleet_scale_sampled():
     assert res["silhouette"] > 0.8
 
 
+@pytest.mark.parametrize("sample", [4096, 64])    # dense and blocked scoring
+def test_choose_k_records_its_runs_and_host_syncs(sample):
+    """One ``grouping.kmeans`` span per (k, restart), one
+    ``grouping.silhouette`` per k, and one ``grouping.sync`` per blocking
+    read: each run's inertia, each k's score, and the labels and centres
+    each time the best k improves."""
+    rng = np.random.default_rng(2)
+    X = np.concatenate([rng.normal(c, 0.05, (60, 3)) for c in (0.0, 1.0, 2.0)])
+    k_max, restarts = 5, 3
+    res = choose_k(X, k_max=k_max, restarts=restarts, silhouette_sample=sample)
+    t = res["timings"]
+    spans = t["spans"]
+    names = [s[0] for s in spans]
+    n_k = k_max - 1
+    improvements = sum(
+        1 for k, s in res["per_k"].items()
+        if all(s > res["per_k"][j] for j in range(2, k)))
+    assert names.count("grouping.kmeans") == t["kmeans_runs"] == n_k * restarts
+    assert names.count("grouping.silhouette") == n_k
+    assert names.count("grouping.standardize") == 1
+    assert (names.count("grouping.sync") == t["host_syncs"]
+            == n_k * restarts + n_k + 2 * improvements)
+    children = {}
+    for name, _, _, parent in spans:
+        if name == "grouping.sync":
+            children.setdefault(parent, []).append(parent)
+    for i, (name, _, _, _) in enumerate(spans):
+        if name in ("grouping.kmeans", "grouping.silhouette"):
+            assert len(children.pop(i)) == 1
+    assert len(children.pop(None)) == 2 * improvements and not children
+    for key in ("standardize_s", "kmeans_s", "silhouette_s", "sync_s"):
+        assert t[key] > 0.0
+
+
 # ---------------------------------------------------------------- labeling
 
 def _info(specs):
