@@ -1,9 +1,10 @@
 """JAX's persistent compilation cache for the repository's entry points.
 
 A jitted program that a later process compiles again at the same shapes
-is read back from this cache instead.  ``run_ensemble`` builds a fresh
-closure on every launch, so only the persistent cache spares its repeats
-a recompile.
+is read back from this cache instead.  Within one process
+``run_ensemble`` keeps the compiled scan of each static signature itself
+and compiles only a new one; this cache spares a later process that
+compile.
 """
 from __future__ import annotations
 

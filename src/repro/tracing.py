@@ -49,7 +49,11 @@ class Record:
             self._open.pop()
             self.totals[key] = self.totals.get(key, 0.0) + entry[2] - entry[1]
             if count is not None:
-                self.totals[count] = self.totals.get(count, 0) + 1
+                self.count(count)
+
+    def count(self, name: str) -> None:
+        """Add one to the record's count of ``name``."""
+        self.totals[name] = self.totals.get(name, 0) + 1
 
     def as_dict(self) -> dict:
         """Seconds per phase and counts, with the spans under ``"spans"``."""

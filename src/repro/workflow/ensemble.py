@@ -64,8 +64,10 @@ disabled/failed nodes  NO
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
-from typing import Optional
+from collections import OrderedDict
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -88,6 +90,9 @@ _BLOCK = 64          # two-level argmin block (tasks pad to a multiple)
 # sits two orders of magnitude above that.
 TPU_TIME_RTOL = 1e-11
 _INT_SENTINEL = 1 << 30
+# Scan programs kept at once (one per static signature, with its compiled
+# executable); enough for the four schedulers of one topology and a few more.
+_PROGRAM_CACHE_SIZE = 8
 
 
 # --------------------------------------------------------------- submissions
@@ -288,17 +293,6 @@ class _Topology:
         self.CAP = int(self.cores_i.max()) // min_rc
         self.S = self.N * self.CAP
 
-        # -- contention denominators as numpy-precomputed lookup tables.
-        #    XLA:CPU contracts ``1.0 + gamma * k`` into an FMA (single
-        #    rounding), which differs from numpy's two-rounding result for
-        #    some running counts — tabulating the denominators on the host
-        #    keeps the scan bit-for-bit with the engine by construction.
-        k_io = np.arange(min(self.S, T) + 2, dtype=np.float64)
-        self.io_denom_table = 1.0 + cfg.io_gamma * np.maximum(0.0, k_io - 1.0)
-        k_mem = np.arange(self.CAP + 2, dtype=np.float64)
-        self.mem_denom_table = np.minimum(
-            1.0 + cfg.mem_beta * np.maximum(0.0, k_mem - 1.0), cfg.mem_cap)
-
         # -- step budget: one finish per step + one idle jump per distinct
         #    future arrival time + slack
         future = np.unique(self.submit_t[:T][self.submit_t[:T] > 0.0])
@@ -317,16 +311,19 @@ class _Topology:
 
         # -- scheduler statics (recomputed from constructor attributes, not
         #    _on_bind products, so the ensemble never mutates the caller's
-        #    scheduler)
+        #    scheduler): sjfn's negated speeds, fillnodes' ranks,
+        #    roundrobin's node order; fair has none
         if self.kind == "sjfn":
-            self.negspeed = np.array(
+            self.sched = np.array(
                 [-round(scheduler.speed[n], -1) for n in self.node_names])
         elif self.kind == "fillnodes":
-            self.rank_arr = np.array(
+            self.sched = np.array(
                 [scheduler._rank[n] for n in self.node_names], np.int32)
         elif self.kind == "roundrobin":
-            self.perm = np.array([na.index[n] for n in scheduler.nodes],
-                                 np.int32)
+            self.sched = np.array([na.index[n] for n in scheduler.nodes],
+                                  np.int32)
+        else:
+            self.sched = np.zeros(0, np.int32)
         self.uniform_demand = bool(
             np.unique(self.req_cores[:T]).size == 1
             and np.unique(self.req_mem[:T]).size == 1)
@@ -356,65 +353,160 @@ class _Topology:
 
 
 # ------------------------------------------------------------------- scan
-def _build_scan(top: _Topology):
-    """Trace-time specialization: one jitted program per (topology shape,
-    scheduler kind, has_arrivals, uniform_demand) combination.
+class _Inputs(NamedTuple):
+    """The topology's arrays and the call's draws, passed to the scan as
+    runtime arguments besides its carry.
 
-    Returns ``(scan, args)``: the jitted program and its runtime arguments
-    (initial carry, per-node cores, per-node memory); ``scan(*args)`` runs
-    every replica to completion.  Build and call it under
-    ``jax.enable_x64(True)``."""
+    None of them is closed over as a trace-time constant, for two reasons.
+    The draws change with every call and the arrays with the topology's
+    values, and a constant that changes makes a new program, so one
+    program per static signature could never be reused.  And ``cores_f``
+    / ``mem_gb`` feed divisions (``free / cores`` in node_load and the
+    occupancy term of node_rates): XLA:CPU strength-reduces division by a *constant* into multiply-by-reciprocal,
+    then fuses ``1 - x*inv`` into an FMA — exact only for power-of-two
+    core counts, a 1-ulp load skew everywhere else that flips argmin
+    placements on mixed clusters.  As arguments the division stays a true
+    division."""
+    cores_f: object            # [N] f64
+    mem_gb: object             # [N] f64
+    cpu_base: object           # [N] f64
+    mem_base: object           # [N] f64
+    io_seq: object             # [N] f64
+    req_cores: object          # [TT] f64
+    req_mem: object            # [TT] f64
+    submit_t: object           # [TT] f64
+    name_idx: object           # [TT] int32
+    dependents: object         # [TT, D] int32
+    work_cpu: object           # [R, TT] f64, this call's draws
+    work_mem: object           # [R, TT] f64
+    work_io: object            # [R, TT] f64
+    sched: object              # _Topology.sched
+
+
+class _Signature(NamedTuple):
+    """Everything the scan's trace reads as a Python value: two topologies
+    with equal signatures run one program.  The ``EngineConfig`` scalars
+    are the step's: ``smt_penalty`` and those of the two contention
+    denominator tables."""
+    kind: str
+    R: int
+    N: int
+    CAP: int
+    TT: int
+    T: int
+    K: int
+    D: int
+    S: int
+    n_steps: int
+    qshift: int
+    has_arrivals: bool
+    uniform_demand: bool
+    fastkey: bool
+    smt_penalty: float
+    io_gamma: float
+    mem_beta: float
+    mem_cap: float
+    shapes: tuple              # (shape, dtype) of every _Inputs field
+    device: object             # jax's default device where set, else None
+
+
+class _Programs:
+    """The scan programs of the most recently used signatures, each with
+    the ``Compiled`` that ``run_ensemble`` made of it.  Bounded, so that a
+    caller sweeping topologies does not hoard executables."""
+
+    def __init__(self, size: int):
+        self.size = size
+        self._entries: OrderedDict = OrderedDict()  # sig -> [scan, compiled]
+        self._lock = threading.Lock()
+
+    def program(self, sig: _Signature):
+        """The jitted scan of ``sig``, made on a miss."""
+        with self._lock:
+            entry = self._entries.get(sig)
+            if entry is None:
+                entry = self._entries[sig] = [_scan_program(sig), None]
+                while len(self._entries) > self.size:
+                    self._entries.popitem(last=False)
+            self._entries.move_to_end(sig)
+            return entry[0]
+
+    def _entry(self, scan):
+        for entry in self._entries.values():
+            if entry[0] is scan:
+                return entry
+        return None
+
+    def compiled(self, scan):
+        """The kept ``Compiled`` of ``scan`` if it is a cached program that
+        has been compiled, else None."""
+        with self._lock:
+            entry = self._entry(scan)
+            return None if entry is None else entry[1]
+
+    def keep(self, scan, compiled) -> None:
+        """Keep ``compiled`` beside ``scan`` if ``scan`` is still cached."""
+        with self._lock:
+            entry = self._entry(scan)
+            if entry is not None:
+                entry[1] = compiled
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+
+_PROGRAMS = _Programs(_PROGRAM_CACHE_SIZE)
+
+
+def _scan_program(sig: _Signature):
+    """The jitted scan of one static signature: ``scan(carry, x)`` runs
+    every replica to completion, ``x`` an :class:`_Inputs` of arrays.  The
+    four schedulers' placement rules and the arrival, non-uniform demand
+    and sjfn fast-key paths are chosen here at trace time."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     from repro.kernels import ensemble_step as ks
 
-    R, N, CAP, TT, T = (top.n_replicas, top.N, top.CAP, top.TT, top.T)
-    K, kind, cfg = top.K, top.kind, top.cfg
+    R, N, CAP, TT, T = sig.R, sig.N, sig.CAP, sig.TT, sig.T
+    K, kind = sig.K, sig.kind
     SENT = jnp.int32(_INT_SENTINEL)
     rr_rows = jnp.arange(R, dtype=jnp.int32)
 
-    # cores_f / mem_gb are deliberately NOT closed over as trace-time
-    # constants: they feed divisions (``free / cores`` in node_load and the
-    # occupancy term of node_rates), and XLA:CPU strength-reduces division
-    # by a *constant* into multiply-by-reciprocal, then fuses ``1 - x*inv``
-    # into an FMA — exact only for power-of-two core counts, a 1-ulp load
-    # skew everywhere else that flips argmin placements on mixed clusters.
-    # They enter ``scan`` as runtime arguments instead (see below), where
-    # the division stays a true division.
-    cpu_base = jnp.asarray(top.cpu_base)
-    mem_base = jnp.asarray(top.mem_base)
-    io_seq = jnp.asarray(top.io_seq)
-    io_denom_table = jnp.asarray(top.io_denom_table)
-    mem_denom_table = jnp.asarray(top.mem_denom_table)
-    req_cores = jnp.asarray(top.req_cores)
-    req_mem = jnp.asarray(top.req_mem)
-    submit_t = jnp.asarray(top.submit_t)
-    seq = jnp.asarray(top.seq)
-    name_idx = jnp.asarray(top.name_idx)
-    dependents = jnp.asarray(top.dependents)
-    work_pad = np.zeros((R, TT, 3))
-    work_pad[:, :T] = top.replica_work()
-    work_cpu = jnp.asarray(work_pad[:, :, 0])
-    work_mem = jnp.asarray(work_pad[:, :, 1])
-    work_io = jnp.asarray(work_pad[:, :, 2])
-    if kind == "sjfn":
-        negspeed = jnp.asarray(top.negspeed)
-    elif kind == "fillnodes":
-        rank_arr = jnp.asarray(top.rank_arr)
-    elif kind == "roundrobin":
-        perm = jnp.asarray(top.perm)
+    # Arrays that depend only on the signature are trace-time constants:
+    # the task slots' promotion ordinals and the contention denominators.
+    # The TPU's f64 emulation folds its handling of constants into the
+    # program; with these as arguments the step took 265.6 us against
+    # 256.3 us (TPU v5 lite, the fleet forecast cell's shapes).  The
+    # denominators are numpy-precomputed lookup tables: XLA:CPU contracts
+    # ``1.0 + gamma * k`` into an FMA (single rounding), which differs from
+    # numpy's two-rounding result for some running counts, so tabulating
+    # them on the host keeps the scan bit-for-bit with the engine.
+    seq = jnp.asarray(np.arange(TT, dtype=np.int32))
+    k_io = np.arange(min(sig.S, T) + 2, dtype=np.float64)
+    io_denom_table = jnp.asarray(
+        1.0 + sig.io_gamma * np.maximum(0.0, k_io - 1.0))
+    k_mem = np.arange(CAP + 2, dtype=np.float64)
+    mem_denom_table = jnp.asarray(np.minimum(
+        1.0 + sig.mem_beta * np.maximum(0.0, k_mem - 1.0), sig.mem_cap))
+    if kind == "roundrobin":
         rr_pos = jnp.arange(N, dtype=jnp.int32)
 
-    def select_node(feas, free_cores, free_mem, rr_i, cores_f, mem_gb):
+    def select_node(feas, free_cores, free_mem, rr_i, x):
         """Masked-argmin twin of ``select_node_idx`` under ordered ties:
         the first-min (lowest index) in the scheduler's key order."""
+        cores_f, mem_gb = x.cores_f, x.mem_gb
         if kind == "fair":
             loads = ks.node_load(free_cores, free_mem, cores_f[None, :],
                                  mem_gb[None, :])
             sel = jnp.argmin(jnp.where(feas, loads, jnp.inf), axis=1)
         elif kind == "sjfn":
+            negspeed = x.sched
             loads = ks.node_load(free_cores, free_mem, cores_f[None, :],
                                  mem_gb[None, :])
             m1 = jnp.min(jnp.where(feas, negspeed[None, :], jnp.inf), axis=1)
@@ -423,27 +515,30 @@ def _build_scan(top: _Topology):
         elif kind == "fillnodes":
             empty = free_cores == cores_f[None, :]
             ikey = jnp.where(empty, N, 0).astype(jnp.int32) \
-                + rank_arr[None, :]
+                + x.sched[None, :]
             sel = jnp.argmin(jnp.where(feas, ikey, SENT), axis=1)
         else:                                    # roundrobin: rotated probe
+            perm = x.sched
             feas_p = feas[:, perm]
             rel = (rr_pos[None, :] - rr_i[:, None]) % N
             pos = jnp.argmin(jnp.where(feas_p, rel, SENT), axis=1)
             return perm[pos].astype(jnp.int32), pos.astype(jnp.int32)
         return sel.astype(jnp.int32), jnp.zeros(R, jnp.int32)
 
-    def step(carry, s, cores_f, mem_gb):
+    def step(carry, s, x):
         (t, free_cores, free_mem, n_running, total_running,
          rem_cpu, rem_mem, rem_io, sord, task_of,
          qrank, deps_left, start_ctr, rr_i, cnt, sm,
          n_finished, node_of, start_t_task, end_t_task, finish_step,
          rank_prev, key_carry) = carry
+        (cores_f, mem_gb, cpu_base, mem_base, io_seq, req_cores, req_mem,
+         submit_t, name_idx, dependents, work_cpu, work_mem, work_io, _) = x
 
         # ---- promote arrivals (engine: _promote_ready at loop top).
         # Finish-readied tasks were stamped by the previous step's
         # dependent scatter with this step's batch base, so the merged
         # batch orders by seq exactly like the engine's sorted() batch.
-        if top.has_arrivals:
+        if sig.has_arrivals:
             prom = (deps_left == 0) & (submit_t[None, :] <= t[:, None])
             qrank = jnp.where(prom, s * TT + seq[None, :], qrank)
             deps_left = jnp.where(prom, -1, deps_left)
@@ -456,7 +551,7 @@ def _build_scan(top: _Topology):
             est = jnp.where(cnt > 0, sm / cnt, jnp.inf)            # [R, K]
             rank = jnp.sum(est[:, None, :] < est[:, :, None],
                            axis=2).astype(jnp.int32)               # [R, K]
-            shift = jnp.int32(top.qshift)
+            shift = jnp.int32(sig.qshift)
 
         def pack_keys(qr):
             rank_task = jnp.take_along_axis(
@@ -481,7 +576,7 @@ def _build_scan(top: _Topology):
         # are maintained as O(R)/O(R·D) point updates below.
         if kind != "sjfn":
             key_task0 = qrank
-        elif top.fastkey:
+        elif sig.fastkey:
             key_task0 = lax.cond(jnp.any(rank != rank_prev),
                                  lambda: pack_keys(qrank),
                                  lambda: key_carry)
@@ -518,7 +613,7 @@ def _build_scan(top: _Topology):
             rm = req_mem[j]
             any_feas = ((free_cores >= rc[:, None])
                         & (free_mem >= rm[:, None])).any(axis=1)
-            if top.uniform_demand:
+            if sig.uniform_demand:
                 return has & any_feas
             left = key_task < SENT
             min_rc = jnp.min(jnp.where(left, req_cores[None, :], jnp.inf),
@@ -551,7 +646,7 @@ def _build_scan(top: _Topology):
             place = has_task & any_feas
             fail = has_task & ~any_feas
             n_sel, rr_pos_sel = select_node(feas, free_cores, free_mem, rr_i,
-                                            cores_f, mem_gb)
+                                            x)
             # retire a failed extraction (the engine appends to `still`;
             # its suffix-min blocked check lives in ``more_to_place``)
             jf = jnp.where(fail, j, T)
@@ -604,7 +699,7 @@ def _build_scan(top: _Topology):
                     start_ctr, rr_i, node_of, start_t_task, jf_last,
                     cont, it + 1)
 
-        cap_iter = TT + top.S + 2
+        cap_iter = TT + sig.S + 2
         bmin0 = key_task0.reshape(R, NB, _BLOCK).min(axis=2)
         cont0 = ((n_finished < T)
                  & more_to_place(free_cores, free_mem, key_task0, bmin0))
@@ -618,7 +713,7 @@ def _build_scan(top: _Topology):
          rem_io, sord, task_of, qrank, key_task, _, start_ctr, rr_i, node_of,
          start_t_task, jf_last, _, _) = st
 
-        if top.fastkey:
+        if sig.fastkey:
             # restore the (single — uniform demand) failed extraction's key
             # from its untouched qrank; the dummy row T gather is gated out
             failedm = jf_last != T
@@ -631,18 +726,18 @@ def _build_scan(top: _Topology):
         # start ordinal == the engine's append-ordered dense-slot argmin)
         cpu, mem = ks.node_rates(free_cores, mem_denom_table[n_running],
                                  cpu_base[None, :], mem_base[None, :],
-                                 cores_f[None, :], cfg.smt_penalty)
+                                 cores_f[None, :], sig.smt_penalty)
         io_eff = io_seq[None, :] / io_denom_table[total_running][:, None]
         tl = ks.time_left(rem_cpu, rem_mem, rem_io, cpu, mem, io_eff)
         active = sord < SENT
         dt, j_slot = ks.first_min_by_order(
-            tl.reshape(R, top.S), sord.reshape(R, top.S),
-            active.reshape(R, top.S))
+            tl.reshape(R, sig.S), sord.reshape(R, sig.S),
+            active.reshape(R, sig.S))
         done = n_finished >= T
         idle = (total_running == 0) & ~done
         do_fin = ~done & ~idle
 
-        if top.has_arrivals:
+        if sig.has_arrivals:
             next_arr = jnp.min(jnp.where(deps_left == 0, submit_t[None, :],
                                          jnp.inf), axis=1)
             t_new = jnp.where(done, t,
@@ -699,7 +794,7 @@ def _build_scan(top: _Topology):
         real = depi != T
         dl = deps_left[rr_rows[:, None], depi] \
             - (do_fin[:, None] & real).astype(jnp.int32)
-        if top.has_arrivals:
+        if sig.has_arrivals:
             ready_now = (dl == 0) & (submit_t[depi] <= t_new[:, None])
         else:
             ready_now = dl == 0
@@ -708,7 +803,7 @@ def _build_scan(top: _Topology):
         dl = jnp.where(ready_now, -1, dl)
         deps_left = deps_left.at[rr_rows[:, None], depi].set(dl)
         qrank = qrank.at[rr_rows[:, None], depi].set(qr)
-        if top.fastkey:
+        if sig.fastkey:
             # stamp the carried key panel too, with this step's ranks — if
             # next step's ranks differ, the lax.cond above rebuilds anyway
             kd = rank[rr_rows[:, None], name_idx[depi]] * shift + qr
@@ -723,6 +818,46 @@ def _build_scan(top: _Topology):
                  deps_left, start_ctr, rr_i, cnt, sm, n_finished, node_of,
                  start_t_task, end_t_task, finish_step,
                  rank_prev, key_carry), None)
+
+    @jax.jit
+    def scan(carry, x):
+        carry, _ = lax.scan(lambda c, s: step(c, s, x), carry,
+                            jnp.arange(sig.n_steps, dtype=jnp.int32))
+        return carry
+
+    return scan
+
+
+def _build_scan(top: _Topology):
+    """The scan program of ``top``'s static signature, looked up in the
+    program cache (made on a miss), and this call's runtime arguments.
+
+    Returns ``(scan, args)``: ``args`` is the initial carry and an
+    :class:`_Inputs` of the topology's arrays and this call's work draws;
+    ``scan(*args)`` runs every replica to completion.  Topologies with the
+    same static signature get the same ``scan`` object.  Build and call it
+    under ``jax.enable_x64(True)``."""
+    import jax
+    import jax.numpy as jnp
+
+    R, N, CAP, TT, T, K = top.n_replicas, top.N, top.CAP, top.TT, top.T, top.K
+    cfg = top.cfg
+    work_pad = np.zeros((R, TT, 3))
+    work_pad[:, :T] = top.replica_work()
+    host = _Inputs(
+        top.cores_f, top.mem_gb, top.cpu_base, top.mem_base, top.io_seq,
+        top.req_cores, top.req_mem, top.submit_t, top.name_idx,
+        top.dependents,
+        work_pad[:, :, 0], work_pad[:, :, 1], work_pad[:, :, 2],
+        top.sched)
+    sig = _Signature(
+        top.kind, R, N, CAP, TT, T, K, top.D, top.S, top.n_steps,
+        top.qshift, top.has_arrivals, top.uniform_demand, top.fastkey,
+        float(cfg.smt_penalty), float(cfg.io_gamma), float(cfg.mem_beta),
+        float(cfg.mem_cap),
+        tuple((a.shape, a.dtype.str) for a in host),
+        jax.config.jax_default_device)
+    scan = _PROGRAMS.program(sig)
 
     # ---- initial carry (numpy-built, converted inside the x64 context)
     qrank0 = np.full((R, TT), _INT_SENTINEL, np.int32)
@@ -749,19 +884,12 @@ def _build_scan(top: _Topology):
         jnp.full((R, TT), -1, jnp.int32),                         # node_of
         jnp.zeros((R, TT)), jnp.zeros((R, TT)),                   # start/end
         jnp.full((R, TT), -1, jnp.int32),                         # finish_step
-        (jnp.full((R, K), -1, jnp.int32) if kind == "sjfn"
+        (jnp.full((R, K), -1, jnp.int32) if top.kind == "sjfn"
          else jnp.zeros((R, 0), jnp.int32)),                      # rank_prev
         (jnp.asarray(qrank0) if top.fastkey
          else jnp.zeros((R, 0), jnp.int32)),                      # key_carry
     )
-
-    @jax.jit
-    def scan(carry, cores_f, mem_gb):
-        carry, _ = lax.scan(lambda c, s: step(c, s, cores_f, mem_gb), carry,
-                            jnp.arange(top.n_steps, dtype=jnp.int32))
-        return carry
-
-    return scan, (carry0, jnp.asarray(top.cores_f), jnp.asarray(top.mem_gb))
+    return scan, (carry0, _Inputs(*(jnp.asarray(a) for a in host)))
 
 
 # ------------------------------------------------------------------ public
@@ -773,14 +901,20 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
     supported feature matrix and the RNG-stream mapping; unsupported
     configurations raise ``NotImplementedError`` at build time.
 
-    The program is compiled and then run once.  ``timings`` is the call's
-    ``tracing.Record``: the seconds of the spans ``ensemble.build``
-    (topology, work draws, closure and uploads), ``ensemble.compile``
-    (lowering and compiling), ``ensemble.run`` (the run, to
-    ``block_until_ready``), ``ensemble.fetch`` (copies to the host and
-    the result) and ``ensemble.release`` (dropping the program and its
-    device buffers) as ``build_s``, ``compile_s``, ``run_s``, ``fetch_s``
-    and ``release_s``; ``compiles``, and the scan's ``n_steps``."""
+    The scan program is cached per static signature (:func:`_build_scan`)
+    with its compiled executable: a call whose topology matches a cached
+    program's signature, with fresh draws or not, runs the kept executable
+    and does no tracing, lowering or compiling; any other call compiles
+    its program once.  The program is then run once.  ``timings`` is the
+    call's ``tracing.Record``: the seconds of the spans ``ensemble.build``
+    (topology, work draws, program lookup and uploads),
+    ``ensemble.compile`` (lowering and compiling, on a miss only),
+    ``ensemble.run`` (the run, to ``block_until_ready``),
+    ``ensemble.fetch`` (copies to the host and the result) and
+    ``ensemble.release`` (dropping the call's device buffers; the program
+    stays cached) as ``build_s``, ``compile_s``, ``run_s``, ``fetch_s``
+    and ``release_s``; the counts ``compiles`` and ``program_hits`` (one
+    of them 1, the other 0), and the scan's ``n_steps``."""
     import jax
 
     rec = tracing.Record()
@@ -789,8 +923,13 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
             top = _Topology(specs, submissions, scheduler, config,
                             n_replicas, seed_stride)
             scan, args = _build_scan(top)
-        with rec.span("ensemble.compile", count="compiles"):
-            compiled = scan.lower(*args).compile()
+        compiled = _PROGRAMS.compiled(scan)
+        if compiled is None:
+            with rec.span("ensemble.compile", count="compiles"):
+                compiled = scan.lower(*args).compile()
+            _PROGRAMS.keep(scan, compiled)
+        else:
+            rec.count("program_hits")
         with rec.span("ensemble.run"):
             out = jax.block_until_ready(compiled(*args))
 
@@ -811,7 +950,8 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
                                     kind="stable").astype(np.int32))
     with rec.span("ensemble.release"):
         del scan, args, compiled, out
-    res.timings = {**rec.as_dict(), "n_steps": top.n_steps}
+    res.timings = {"compiles": 0, "program_hits": 0, **rec.as_dict(),
+                   "n_steps": top.n_steps}
     return res
 
 

@@ -262,3 +262,117 @@ def test_scan_module_is_named_jit_scan():
         scan, args = ensemble._build_scan(top)
         text = scan.lower(*args).compile().as_text()
     assert text.startswith("HloModule jit_scan,")
+
+
+# ------------------------------------------------------- program reuse
+def _reuse_case():
+    """A mixed-core cluster (divisions by 4-, 8- and 16-core counts) and
+    one random DAG."""
+    rng = np.random.default_rng(5)
+    return random_cluster(rng), random_workflow(rng, "wfa")
+
+
+def _forecast(specs, subs, sched_name, n_replicas=2, config=None):
+    return run_ensemble(specs, subs, make_scheduler(sched_name, specs, seed=0),
+                        n_replicas, config=config)
+
+
+def _oracle(specs, subs, sched_name, n_replicas=2, config=None):
+    return oracle_ensemble(specs, subs,
+                           make_scheduler(sched_name, specs, seed=0),
+                           n_replicas, config=config)
+
+
+@pytest.mark.parametrize("sched_name", _SCHEDS)
+def test_repeat_topology_reuses_the_compiled_program(sched_name):
+    """A second call on one topology with fresh draws runs the kept
+    executable: no tracing, lowering or compiling, no compile span, and
+    the results of a cold call on the same draws, bit for bit."""
+    import jax
+
+    from repro.workflow import ensemble
+
+    specs, wf = _reuse_case()
+    subs = lambda seed: [Submission(wf, seed=seed, prefix="a")]
+    ensemble._PROGRAMS.clear()
+    first = _forecast(specs, subs(1), sched_name)
+    events = []
+
+    def on_duration(event, duration, **_):
+        if event.startswith("/jax/core/compile/"):
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        warm = _forecast(specs, subs(2), sched_name)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert (first.timings["compiles"], first.timings["program_hits"]) == (1, 0)
+    assert (warm.timings["compiles"], warm.timings["program_hits"]) == (0, 1)
+    assert events == []
+    assert "compile_s" not in warm.timings
+    assert [name for name, *_ in warm.timings["spans"]] == [
+        "ensemble.build", "ensemble.run", "ensemble.fetch", "ensemble.release"]
+    assert_equivalent(first, _oracle(specs, subs(1), sched_name))
+    assert_equivalent(warm, _oracle(specs, subs(2), sched_name))
+    ensemble._PROGRAMS.clear()
+    cold = _forecast(specs, subs(2), sched_name)
+    assert cold.timings["compiles"] == 1
+    assert_equivalent(warm, cold)
+
+
+@pytest.mark.parametrize("change", ["replicas", "scheduler", "smt_penalty",
+                                    "io_gamma", "mem_beta", "mem_cap",
+                                    "arrivals"])
+def test_another_signature_compiles_anew(change):
+    """Each part of the static signature that the trace reads makes a
+    program of its own, and the program before it stays cached."""
+    from repro.workflow import ensemble
+
+    specs, wf = _reuse_case()
+    base = {"subs": [Submission(wf, seed=1, prefix="a")],
+            "sched_name": "fair", "n_replicas": 2, "config": None}
+    other = dict(base)
+    if change == "replicas":
+        other["n_replicas"] = 3
+    elif change == "scheduler":
+        other["sched_name"] = "sjfn"
+    elif change in ("smt_penalty", "io_gamma", "mem_beta", "mem_cap"):
+        # the step's EngineConfig scalars, each changed alone
+        scale = 0.5 if change == "mem_cap" else 2.0
+        other["config"] = EngineConfig(
+            **{change: getattr(EngineConfig(), change) * scale})
+    else:
+        other["subs"] = [Submission(wf, seed=1, prefix="a", at=5.0)]
+    ensemble._PROGRAMS.clear()
+    assert _forecast(specs, **base).timings["compiles"] == 1
+    res = _forecast(specs, **other)
+    assert (res.timings["compiles"], res.timings["program_hits"]) == (1, 0)
+    assert_equivalent(res, _oracle(specs, **other))
+    assert _forecast(specs, **base).timings["program_hits"] == 1
+
+
+def test_program_cache_evicts_past_its_bound(monkeypatch):
+    """The least recently used program goes once the cache is full; a
+    lookup of a cached signature refreshes it."""
+    import jax
+
+    from repro.workflow import ensemble
+
+    monkeypatch.setattr(ensemble, "_PROGRAMS", ensemble._Programs(2))
+    specs = _specs()
+
+    def build(n_replicas):
+        top = ensemble._Topology(specs, [Submission(_toy())],
+                                 make_scheduler("fair", specs, seed=0), None,
+                                 n_replicas, 1)
+        with jax.enable_x64(True):
+            return ensemble._build_scan(top)[0]
+
+    a, b = build(1), build(2)
+    assert build(1) is a
+    c = build(3)                              # evicts b, not a
+    assert len(ensemble._PROGRAMS) == 2
+    assert build(1) is a and build(3) is c
+    assert build(2) is not b
+    assert len(ensemble._PROGRAMS) == 2
