@@ -51,9 +51,9 @@ class Record:
             if count is not None:
                 self.count(count)
 
-    def count(self, name: str) -> None:
-        """Add one to the record's count of ``name``."""
-        self.totals[name] = self.totals.get(name, 0) + 1
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the record's count of ``name``."""
+        self.totals[name] = self.totals.get(name, 0) + n
 
     def as_dict(self) -> dict:
         """Seconds per phase and counts, with the spans under ``"spans"``."""

@@ -46,6 +46,11 @@ modulo one documented RNG-stream mapping:
   finite f64 mean is the one measure-zero case where the orders could
   differ.
 
+The scan's two counters, ``key_rebuilds`` and ``place_iters`` (see
+:func:`run_ensemble`), count the program's own work and have no twin in
+the engine: they lie outside this contract, and reading them changes no
+decision or time.
+
 Supported feature matrix (anything else raises ``NotImplementedError``
 loudly at build time rather than silently diverging):
 
@@ -530,7 +535,7 @@ def _scan_program(sig: _Signature):
          rem_cpu, rem_mem, rem_io, sord, task_of,
          qrank, deps_left, start_ctr, rr_i, cnt, sm,
          n_finished, node_of, start_t_task, end_t_task, finish_step,
-         rank_prev, key_carry) = carry
+         rank_prev, key_carry, key_rebuilds, place_iters) = carry
         (cores_f, mem_gb, cpu_base, mem_base, io_seq, req_cores, req_mem,
          submit_t, name_idx, dependents, work_cpu, work_mem, work_io, _) = x
 
@@ -577,9 +582,10 @@ def _scan_program(sig: _Signature):
         if kind != "sjfn":
             key_task0 = qrank
         elif sig.fastkey:
-            key_task0 = lax.cond(jnp.any(rank != rank_prev),
-                                 lambda: pack_keys(qrank),
+            rebuild = jnp.any(rank != rank_prev)
+            key_task0 = lax.cond(rebuild, lambda: pack_keys(qrank),
                                  lambda: key_carry)
+            key_rebuilds = key_rebuilds + rebuild.astype(jnp.int32)
         else:
             key_task0 = pack_keys(qrank)
 
@@ -711,7 +717,8 @@ def _scan_program(sig: _Signature):
              jnp.full(R, T, jnp.int32), cont0, 0))
         (free_cores, free_mem, n_running, total_running, rem_cpu, rem_mem,
          rem_io, sord, task_of, qrank, key_task, _, start_ctr, rr_i, node_of,
-         start_t_task, jf_last, _, _) = st
+         start_t_task, jf_last, _, it) = st
+        place_iters = place_iters + it.astype(jnp.int32)
 
         if sig.fastkey:
             # restore the (single — uniform demand) failed extraction's key
@@ -817,7 +824,7 @@ def _scan_program(sig: _Signature):
                  rem_cpu, rem_mem, rem_io, sord, task_of, qrank,
                  deps_left, start_ctr, rr_i, cnt, sm, n_finished, node_of,
                  start_t_task, end_t_task, finish_step,
-                 rank_prev, key_carry), None)
+                 rank_prev, key_carry, key_rebuilds, place_iters), None)
 
     @jax.jit
     def scan(carry, x):
@@ -888,6 +895,10 @@ def _build_scan(top: _Topology):
          else jnp.zeros((R, 0), jnp.int32)),                      # rank_prev
         (jnp.asarray(qrank0) if top.fastkey
          else jnp.zeros((R, 0), jnp.int32)),                      # key_carry
+        # key_rebuilds: without the fast path every step builds its keys
+        # afresh, so the count starts at the step count and stays there
+        jnp.int32(0 if top.fastkey else top.n_steps),
+        jnp.int32(0),                                             # place_iters
     )
     return scan, (carry0, _Inputs(*(jnp.asarray(a) for a in host)))
 
@@ -914,7 +925,13 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
     ``ensemble.release`` (dropping the call's device buffers; the program
     stays cached) as ``build_s``, ``compile_s``, ``run_s``, ``fetch_s``
     and ``release_s``; the counts ``compiles`` and ``program_hits`` (one
-    of them 1, the other 0), and the scan's ``n_steps``."""
+    of them 1, the other 0), and the scan's ``n_steps``.  Two counts come
+    from inside the scan: ``key_rebuilds``, the steps on which sjfn's fast
+    path rebuilt its carried key panel (the ``lax.cond`` took
+    ``pack_keys``; every other signature builds its extraction keys afresh
+    on every step, so there it is ``n_steps``, set before the run), and
+    ``place_iters``, the placement ``while_loop``'s iterations summed over
+    the steps (an iteration serves every replica at once)."""
     import jax
 
     rec = tracing.Record()
@@ -948,6 +965,8 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
             start_t=np.asarray(out[18])[:, :T], end_t=end_t,
             finish_order=np.argsort(fstep, axis=1,
                                     kind="stable").astype(np.int32))
+        rec.count("key_rebuilds", int(out[23]))
+        rec.count("place_iters", int(out[24]))
     with rec.span("ensemble.release"):
         del scan, args, compiled, out
     res.timings = {"compiles": 0, "program_hits": 0, **rec.as_dict(),

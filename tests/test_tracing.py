@@ -41,6 +41,16 @@ def test_nested_spans_record_parent_duration_and_counts():
     assert out["inners"] == 2 and "outers" not in out
 
 
+def test_counts_add_an_amount():
+    rec = tracing.Record()
+    rec.count("steps", 306)
+    rec.count("steps", 0)
+    with rec.span("entry.phase", count="phases"):
+        rec.count("steps", 5)
+    out = rec.as_dict()
+    assert out["steps"] == 311 and out["phases"] == 1
+
+
 def test_spans_lie_in_the_profiler_trace_on_the_callers_thread(tmp_path):
     from jax.profiler import ProfileData
 
