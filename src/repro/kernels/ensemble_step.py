@@ -131,6 +131,24 @@ def blocked_argmin_i32(key, block: int):
     return (b * block + within).astype(jnp.int32)
 
 
+def rank_of_names(rank, onehot):
+    """``rank[r, name]`` looked up by a one-hot select-and-sum, no gather.
+
+    rank: [R, K] int32, a rank per replica and name.  onehot: bool
+    [R or 1, ..., K], True at each entry's name; its leading axis is the
+    replica axis, of size 1 for names shared by every replica.  Returns
+    [R, ...] int32: [1, TT, K] gives [R, TT], [R, K] gives [R], [R, D, K]
+    gives [R, D].  Exact: each sum has one non-zero term.
+
+    A batched gather from ``rank`` runs element by element on a TPU (683 us
+    for [256, 320] on a v5 lite); the [R, TT, K] select fuses into its
+    reduction and is never materialised.
+    """
+    R, K = rank.shape
+    r = rank.reshape(R, *(1,) * (onehot.ndim - 2), K)
+    return jnp.sum(jnp.where(onehot, r, 0), axis=-1, dtype=jnp.int32)
+
+
 def node_load(free_cores, free_mem, cores, mem_gb):
     """``allocation.node_loads`` batched: 0.5 * ((1 - free_cores/cores)
     + (1 - free_mem/mem)) — operand-for-operand, so masked argmins over it

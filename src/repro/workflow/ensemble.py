@@ -530,7 +530,7 @@ def _scan_program(sig: _Signature):
             return perm[pos].astype(jnp.int32), pos.astype(jnp.int32)
         return sel.astype(jnp.int32), jnp.zeros(R, jnp.int32)
 
-    def step(carry, s, x):
+    def step(carry, s, x, name_onehot):
         (t, free_cores, free_mem, n_running, total_running,
          rem_cpu, rem_mem, rem_io, sord, task_of,
          qrank, deps_left, start_ctr, rr_i, cnt, sm,
@@ -559,8 +559,7 @@ def _scan_program(sig: _Signature):
             shift = jnp.int32(sig.qshift)
 
         def pack_keys(qr):
-            rank_task = jnp.take_along_axis(
-                rank, jnp.broadcast_to(name_idx[None, :], (R, TT)), axis=1)
+            rank_task = ks.rank_of_names(rank, name_onehot[None])  # [R, TT]
             return jnp.where(qr < SENT, rank_task * shift + qr, SENT)
 
         # The queue is static within one placement pass (promotions happen
@@ -576,9 +575,11 @@ def _scan_program(sig: _Signature):
         # sjfn fast path (uniform demand, no delayed arrivals — the fleet
         # bench shape): the name-rank ordering changes rarely once runtime
         # estimates separate, so the packed panel is carried across steps
-        # and the full [R, TT] gather+pack re-runs only on steps where the
+        # and the full [R, TT] lookup+pack re-runs only on steps where the
         # rank vector actually moved; placements/fails/readied dependents
-        # are maintained as O(R)/O(R·D) point updates below.
+        # are maintained as O(R)/O(R·D) point updates below.  Every rank
+        # lookup selects from the name one-hot (``ks.rank_of_names``), never
+        # gathers from the [R, K] rank table (element by element on a TPU).
         if kind != "sjfn":
             key_task0 = qrank
         elif sig.fastkey:
@@ -724,7 +725,7 @@ def _scan_program(sig: _Signature):
             # restore the (single — uniform demand) failed extraction's key
             # from its untouched qrank; the dummy row T gather is gated out
             failedm = jf_last != T
-            kold = (rank[rr_rows, name_idx[jf_last]] * shift
+            kold = (ks.rank_of_names(rank, name_onehot[jf_last]) * shift
                     + qrank[rr_rows, jf_last])
             key_task = key_task.at[rr_rows, jf_last].set(
                 jnp.where(failedm, kold, key_task[rr_rows, jf_last]))
@@ -813,7 +814,7 @@ def _scan_program(sig: _Signature):
         if sig.fastkey:
             # stamp the carried key panel too, with this step's ranks — if
             # next step's ranks differ, the lax.cond above rebuilds anyway
-            kd = rank[rr_rows[:, None], name_idx[depi]] * shift + qr
+            kd = ks.rank_of_names(rank, name_onehot[depi]) * shift + qr
             key_carry = key_task.at[rr_rows[:, None], depi].set(
                 jnp.where(ready_now, kd,
                           key_task[rr_rows[:, None], depi]))
@@ -828,7 +829,11 @@ def _scan_program(sig: _Signature):
 
     @jax.jit
     def scan(carry, x):
-        carry, _ = lax.scan(lambda c, s: step(c, s, x), carry,
+        # sjfn's [TT, K] name one-hot, built once per call for its rank
+        # lookups; the other schedulers read no rank
+        name_onehot = (x.name_idx[:, None] == jnp.arange(K, dtype=jnp.int32)
+                       if kind == "sjfn" else None)
+        carry, _ = lax.scan(lambda c, s: step(c, s, x, name_onehot), carry,
                             jnp.arange(sig.n_steps, dtype=jnp.int32))
         return carry
 

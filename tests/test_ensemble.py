@@ -264,6 +264,37 @@ def test_scan_module_is_named_jit_scan():
     assert text.startswith("HloModule jit_scan,")
 
 
+@pytest.mark.parametrize("fastkey", [True, False],
+                         ids=["fastkey", "mixed-demand"])
+def test_sjfn_scan_has_no_gather_on_the_rank_table(fastkey):
+    """sjfn looks task names up in its [R, K] int32 rank table by a one-hot
+    select (``ensemble_step.rank_of_names``): a gather from that table ran
+    element by element on the TPU.  The gather's operand types end its
+    line in the lowered text, the table's first."""
+    import re
+
+    import jax
+
+    from repro.workflow import ensemble
+
+    if fastkey:
+        specs = cluster_5442()
+        subs = [Submission(WORKFLOWS["cageseq"](), seed=4, prefix="c"),
+                Submission(WORKFLOWS["eager"](), seed=5, prefix="e")]
+    else:
+        specs, wf = _reuse_case()
+        subs = [Submission(wf, seed=1, prefix="a")]
+    top = ensemble._Topology(specs, subs, make_scheduler("sjfn", specs, seed=0),
+                             None, 3, 1)
+    assert top.fastkey == fastkey
+    with jax.enable_x64(True):
+        scan, args = ensemble._build_scan(top)
+        text = scan.lower(*args).as_text()
+    operands = re.findall(r'"stablehlo\.gather".*: \((tensor<[^>]*>)', text)
+    assert operands                       # the pattern reads the other gathers
+    assert f"tensor<{top.n_replicas}x{top.K}xi32>" not in operands
+
+
 # ------------------------------------------------------- program reuse
 def _reuse_case():
     """A mixed-core cluster (divisions by 4-, 8- and 16-core counts) and

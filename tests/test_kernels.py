@@ -187,3 +187,29 @@ def test_ensemble_blocked_argmin_matches_flat_argmin():
     key[0, :] = int(ks.INT_SENTINEL)                    # empty row
     got = ks.blocked_argmin_i32(jnp.asarray(key), B)
     np.testing.assert_array_equal(np.asarray(got), key.argmin(axis=1))
+
+
+@pytest.mark.parametrize("R,TT,K", [(3, 64, 1), (5, 128, 7), (256, 320, 34)])
+@pytest.mark.parametrize("form", ["TTxK", "RxK", "RxDxK"])
+def test_ensemble_rank_of_names_matches_fancy_indexing(R, TT, K, form):
+    """The one-hot lookup is ``rank[r, name]`` exactly, in the three forms
+    the sjfn step uses: the task panel shared by every replica, one task
+    per replica, and D dependents per replica."""
+    from repro.kernels import ensemble_step as ks
+    rng = np.random.default_rng(K)
+    rank = rng.integers(0, 1 << 20, (R, K)).astype(np.int32)
+    name_idx = rng.integers(0, K, TT).astype(np.int32)
+    name_idx[TT - TT // 4:] = 0                       # padded task rows
+    rows = np.arange(R)
+    if form == "TTxK":
+        idx, want = name_idx[None, :], rank[:, name_idx]
+    elif form == "RxK":
+        idx = name_idx[rng.integers(0, TT, R)]
+        want = rank[rows, idx]
+    else:
+        idx = name_idx[rng.integers(0, TT, (R, 3))]
+        want = rank[rows[:, None], idx]
+    onehot = idx[..., None] == np.arange(K)
+    got = ks.rank_of_names(jnp.asarray(rank), jnp.asarray(onehot))
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
