@@ -6,8 +6,8 @@ ready promotion, and the fair / sjfn / fillnodes / roundrobin placement
 rules as masked argmins — into a single ``lax.scan`` step function,
 batched over a leading replica axis so hundreds of Monte-Carlo replicas
 (same DAG + cluster, different per-replica work jitter) execute as ONE
-jitted XLA program.  ``benchmarks/ensemble_bench.py`` measures the
-resulting replicas/sec against the sequential numpy engine.
+jitted XLA program.  ``bench/run.py`` measures its replicas/s on the
+chip, in the forecast cells of ``BENCHMARK.json``.
 
 Equivalence contract
 --------------------
@@ -46,10 +46,11 @@ modulo one documented RNG-stream mapping:
   finite f64 mean is the one measure-zero case where the orders could
   differ.
 
-The scan's two counters, ``key_rebuilds`` and ``place_iters`` (see
-:func:`run_ensemble`), count the program's own work and have no twin in
-the engine: they lie outside this contract, and reading them changes no
-decision or time.
+The call's two work counts (see :func:`run_ensemble`) have no twin in
+the engine and lie outside this contract: ``place_iters`` is counted
+inside the scan, and reading it changes no decision or time;
+``key_rebuilds`` is ``n_steps``, set on the host, since every signature
+builds its extraction keys afresh on every step.
 
 Supported feature matrix (anything else raises ``NotImplementedError``
 loudly at build time rather than silently diverging):
@@ -332,12 +333,6 @@ class _Topology:
         self.uniform_demand = bool(
             np.unique(self.req_cores[:T]).size == 1
             and np.unique(self.req_mem[:T]).size == 1)
-        # sjfn fast path: carry the packed extraction keys across steps and
-        # rebuild only when the name-rank ordering moves (needs uniform
-        # demand — at most one failed extraction per pass to restore — and
-        # no delayed arrivals, whose promotions would dirty the panel)
-        self.fastkey = (self.kind == "sjfn" and self.uniform_demand
-                        and not self.has_arrivals)
 
     # -- per-replica inputs -------------------------------------------------
     def replica_work(self) -> np.ndarray:
@@ -388,6 +383,56 @@ class _Inputs(NamedTuple):
     sched: object              # _Topology.sched
 
 
+class _Carry(NamedTuple):
+    """The scan's carry: every replica's state between steps."""
+    t: object                  # [R] f64 simulated time
+    free_cores: object         # [R, N] f64
+    free_mem: object           # [R, N] f64
+    n_running: object          # [R, N] int32
+    total_running: object      # [R] int32
+    rem_cpu: object            # [R, N, CAP] f64 work left in each slot
+    rem_mem: object            # [R, N, CAP] f64
+    rem_io: object             # [R, N, CAP] f64
+    sord: object               # [R, N, CAP] int32 start ordinal, SENT if free
+    task_of: object            # [R, N, CAP] int32 task in the slot
+    qrank: object              # [R, TT] int32 queue key, SENT if not queued
+    deps_left: object          # [R, TT] int32, -1 once queued
+    start_ctr: object          # [R] int32 next start ordinal
+    rr_i: object               # [R] int32 roundrobin's cursor
+    cnt: object                # [R, K] f64 sjfn's finishes per task name
+    sm: object                 # [R, K] f64 sjfn's runtime sum per task name
+    n_finished: object         # [R] int32
+    node_of: object            # [R, TT] int32, -1 until placed
+    start_t_task: object       # [R, TT] f64
+    end_t_task: object         # [R, TT] f64
+    finish_step: object        # [R, TT] int32, -1 until finished
+    place_iters: object        # int32 placement-loop iterations so far
+
+
+class _Place(NamedTuple):
+    """The placement ``while_loop``'s state within one step: the carry's
+    fields a placement writes, the pass's extraction keys and their block
+    minima, and the loop's condition and iteration count."""
+    free_cores: object
+    free_mem: object
+    n_running: object
+    total_running: object
+    rem_cpu: object
+    rem_mem: object
+    rem_io: object
+    sord: object
+    task_of: object
+    qrank: object
+    key_task: object           # [R, TT] int32 extraction key, SENT if tried
+    bmin: object               # [R, TT // _BLOCK] int32 block minima
+    start_ctr: object
+    rr_i: object
+    node_of: object
+    start_t_task: object
+    cont: object               # [R] bool: the pass has more to place
+    it: object                 # int32 iterations so far
+
+
 class _Signature(NamedTuple):
     """Everything the scan's trace reads as a Python value: two topologies
     with equal signatures run one program.  The ``EngineConfig`` scalars
@@ -406,7 +451,6 @@ class _Signature(NamedTuple):
     qshift: int
     has_arrivals: bool
     uniform_demand: bool
-    fastkey: bool
     smt_penalty: float
     io_gamma: float
     mem_beta: float
@@ -470,8 +514,8 @@ _PROGRAMS = _Programs(_PROGRAM_CACHE_SIZE)
 def _scan_program(sig: _Signature):
     """The jitted scan of one static signature: ``scan(carry, x)`` runs
     every replica to completion, ``x`` an :class:`_Inputs` of arrays.  The
-    four schedulers' placement rules and the arrival, non-uniform demand
-    and sjfn fast-key paths are chosen here at trace time."""
+    four schedulers' placement rules and the arrival and non-uniform
+    demand paths are chosen here at trace time."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -530,14 +574,10 @@ def _scan_program(sig: _Signature):
             return perm[pos].astype(jnp.int32), pos.astype(jnp.int32)
         return sel.astype(jnp.int32), jnp.zeros(R, jnp.int32)
 
-    def step(carry, s, x, name_onehot):
-        (t, free_cores, free_mem, n_running, total_running,
-         rem_cpu, rem_mem, rem_io, sord, task_of,
-         qrank, deps_left, start_ctr, rr_i, cnt, sm,
-         n_finished, node_of, start_t_task, end_t_task, finish_step,
-         rank_prev, key_carry, key_rebuilds, place_iters) = carry
+    def step(c, s, x, name_onehot):
         (cores_f, mem_gb, cpu_base, mem_base, io_seq, req_cores, req_mem,
          submit_t, name_idx, dependents, work_cpu, work_mem, work_io, _) = x
+        t, qrank, deps_left = c.t, c.qrank, c.deps_left
 
         # ---- promote arrivals (engine: _promote_ready at loop top).
         # Finish-readied tasks were stamped by the previous step's
@@ -551,44 +591,24 @@ def _scan_program(sig: _Signature):
         # ---- placement pass (engine: scheduler.order + _place_array):
         # repeatedly extract the least-key untried queued task; place it on
         # the scheduler's argmin node, or mark it tried and stop once the
-        # remaining per-dim minimum demand fits on no node.
+        # remaining per-dim minimum demand fits on no node.  The queue is
+        # static within one pass (promotions happen at step start;
+        # finish-readied tasks are stamped for the *next* step), so the
+        # extraction key is built once per step: qrank, or for sjfn the
+        # name rank packed above it.  The rank lookup selects from the name
+        # one-hot (``ks.rank_of_names``), never gathers from the [R, K] rank
+        # table (element by element on a TPU).  In the pass a placed task
+        # and a *failed* extraction (the engine's append-to-``still``) flip
+        # to SENT, so no "already tried this step" array is needed.
         if kind == "sjfn":
-            est = jnp.where(cnt > 0, sm / cnt, jnp.inf)            # [R, K]
+            est = jnp.where(c.cnt > 0, c.sm / c.cnt, jnp.inf)      # [R, K]
             rank = jnp.sum(est[:, None, :] < est[:, :, None],
                            axis=2).astype(jnp.int32)               # [R, K]
-            shift = jnp.int32(sig.qshift)
-
-        def pack_keys(qr):
             rank_task = ks.rank_of_names(rank, name_onehot[None])  # [R, TT]
-            return jnp.where(qr < SENT, rank_task * shift + qr, SENT)
-
-        # The queue is static within one placement pass (promotions happen
-        # at step start, finish-readied tasks are stamped for the *next*
-        # step), so the packed extraction key is computed once per step and
-        # kept current incrementally: placed tasks flip to SENT exactly
-        # like qrank, and a *failed* extraction flips to SENT too — the
-        # engine's append-to-``still`` — the key panel is restored from
-        # qrank before the next pass.  This removes both the per-iteration
-        # rank*shift+qrank pack (sjfn) and the per-iteration tried-epoch
-        # compare that an explicit "already tried this step" array needs.
-        #
-        # sjfn fast path (uniform demand, no delayed arrivals — the fleet
-        # bench shape): the name-rank ordering changes rarely once runtime
-        # estimates separate, so the packed panel is carried across steps
-        # and the full [R, TT] lookup+pack re-runs only on steps where the
-        # rank vector actually moved; placements/fails/readied dependents
-        # are maintained as O(R)/O(R·D) point updates below.  Every rank
-        # lookup selects from the name one-hot (``ks.rank_of_names``), never
-        # gathers from the [R, K] rank table (element by element on a TPU).
-        if kind != "sjfn":
-            key_task0 = qrank
-        elif sig.fastkey:
-            rebuild = jnp.any(rank != rank_prev)
-            key_task0 = lax.cond(rebuild, lambda: pack_keys(qrank),
-                                 lambda: key_carry)
-            key_rebuilds = key_rebuilds + rebuild.astype(jnp.int32)
+            key_task0 = jnp.where(
+                qrank < SENT, rank_task * jnp.int32(sig.qshift) + qrank, SENT)
         else:
-            key_task0 = pack_keys(qrank)
+            key_task0 = qrank
 
         # Extraction is a two-level min: per-block minima (bmin, [R, NB])
         # are carried through the loop and only the winning block's 64-wide
@@ -636,113 +656,105 @@ def _scan_program(sig: _Signature):
             return has & (any_feas | fitmin)
 
         def place_body(st):
-            (free_cores, free_mem, n_running, total_running, rem_cpu,
-             rem_mem, rem_io, sord, task_of, qrank, key_task, bmin,
-             start_ctr, rr_i, node_of, start_t_task, jf_last, cont, it) = st
-            b = jnp.argmin(bmin, axis=1).astype(jnp.int32)
-            rows = jnp.take_along_axis(key_task.reshape(R, NB, _BLOCK),
+            b = jnp.argmin(st.bmin, axis=1).astype(jnp.int32)
+            rows = jnp.take_along_axis(st.key_task.reshape(R, NB, _BLOCK),
                                        b[:, None, None], axis=1)[:, 0, :]
             within = jnp.argmin(rows, axis=1).astype(jnp.int32)
             j = b * _BLOCK + within
             kmin = rows[rr_rows, within]
-            has_task = (kmin < SENT) & cont
+            has_task = (kmin < SENT) & st.cont
             rc = req_cores[j]
             rm = req_mem[j]
-            feas = (free_cores >= rc[:, None]) & (free_mem >= rm[:, None])
+            feas = ((st.free_cores >= rc[:, None])
+                    & (st.free_mem >= rm[:, None]))
             any_feas = feas.any(axis=1)
             place = has_task & any_feas
             fail = has_task & ~any_feas
-            n_sel, rr_pos_sel = select_node(feas, free_cores, free_mem, rr_i,
-                                            x)
+            n_sel, rr_pos_sel = select_node(feas, st.free_cores, st.free_mem,
+                                            st.rr_i, x)
             # retire a failed extraction (the engine appends to `still`;
             # its suffix-min blocked check lives in ``more_to_place``)
             jf = jnp.where(fail, j, T)
-            key_task = key_task.at[rr_rows, jf].set(
-                jnp.where(fail, SENT, key_task[rr_rows, jf]))
-            jf_last = jnp.where(fail, j, jf_last)
+            key_task = st.key_task.at[rr_rows, jf].set(
+                jnp.where(fail, SENT, st.key_task[rr_rows, jf]))
             # apply the placement (per-replica gated scatters; dummies
             # target task row T / node 0 and rewrite the existing value)
             jp = jnp.where(place, j, T)
             npl = jnp.where(place, n_sel, 0)
-            c_sel = jnp.argmax(sord[rr_rows, npl] == SENT, axis=1)
-            old_fc = free_cores[rr_rows, npl]
-            old_fm = free_mem[rr_rows, npl]
-            free_cores = free_cores.at[rr_rows, npl].set(
+            c_sel = jnp.argmax(st.sord[rr_rows, npl] == SENT, axis=1)
+            old_fc = st.free_cores[rr_rows, npl]
+            old_fm = st.free_mem[rr_rows, npl]
+            free_cores = st.free_cores.at[rr_rows, npl].set(
                 jnp.where(place, old_fc - rc, old_fc))
-            free_mem = free_mem.at[rr_rows, npl].set(
+            free_mem = st.free_mem.at[rr_rows, npl].set(
                 jnp.where(place, old_fm - rm, old_fm))
-            n_running = n_running.at[rr_rows, npl].add(
+            n_running = st.n_running.at[rr_rows, npl].add(
                 place.astype(jnp.int32))
-            total_running = total_running + place.astype(jnp.int32)
+            total_running = st.total_running + place.astype(jnp.int32)
             old = lambda a: a[rr_rows, npl, c_sel]
-            rem_cpu = rem_cpu.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, work_cpu[rr_rows, jp], old(rem_cpu)))
-            rem_mem = rem_mem.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, work_mem[rr_rows, jp], old(rem_mem)))
-            rem_io = rem_io.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, work_io[rr_rows, jp], old(rem_io)))
-            sord = sord.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, start_ctr, old(sord)))
-            task_of = task_of.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, j, old(task_of)))
-            qrank = qrank.at[rr_rows, jp].set(
-                jnp.where(place, SENT, qrank[rr_rows, jp]))
+            rem_cpu = st.rem_cpu.at[rr_rows, npl, c_sel].set(
+                jnp.where(place, work_cpu[rr_rows, jp], old(st.rem_cpu)))
+            rem_mem = st.rem_mem.at[rr_rows, npl, c_sel].set(
+                jnp.where(place, work_mem[rr_rows, jp], old(st.rem_mem)))
+            rem_io = st.rem_io.at[rr_rows, npl, c_sel].set(
+                jnp.where(place, work_io[rr_rows, jp], old(st.rem_io)))
+            sord = st.sord.at[rr_rows, npl, c_sel].set(
+                jnp.where(place, st.start_ctr, old(st.sord)))
+            task_of = st.task_of.at[rr_rows, npl, c_sel].set(
+                jnp.where(place, j, old(st.task_of)))
+            qrank = st.qrank.at[rr_rows, jp].set(
+                jnp.where(place, SENT, st.qrank[rr_rows, jp]))
             key_task = key_task.at[rr_rows, jp].set(
                 jnp.where(place, SENT, key_task[rr_rows, jp]))
             retired = place | fail
             rows = rows.at[rr_rows, within].set(
                 jnp.where(retired, SENT, kmin))
-            bmin = bmin.at[rr_rows, b].set(jnp.min(rows, axis=1))
-            node_of = node_of.at[rr_rows, jp].set(
-                jnp.where(place, n_sel, node_of[rr_rows, jp]))
-            start_t_task = start_t_task.at[rr_rows, jp].set(
-                jnp.where(place, t, start_t_task[rr_rows, jp]))
-            start_ctr = start_ctr + place.astype(jnp.int32)
+            bmin = st.bmin.at[rr_rows, b].set(jnp.min(rows, axis=1))
+            node_of = st.node_of.at[rr_rows, jp].set(
+                jnp.where(place, n_sel, st.node_of[rr_rows, jp]))
+            start_t_task = st.start_t_task.at[rr_rows, jp].set(
+                jnp.where(place, t, st.start_t_task[rr_rows, jp]))
+            start_ctr = st.start_ctr + place.astype(jnp.int32)
+            rr_i = st.rr_i
             if kind == "roundrobin":
                 rr_i = jnp.where(place, (rr_pos_sel + 1) % N, rr_i)
             cont = more_to_place(free_cores, free_mem, key_task, bmin)
-            return (free_cores, free_mem, n_running, total_running, rem_cpu,
-                    rem_mem, rem_io, sord, task_of, qrank, key_task, bmin,
-                    start_ctr, rr_i, node_of, start_t_task, jf_last,
-                    cont, it + 1)
+            return _Place(
+                free_cores=free_cores, free_mem=free_mem, n_running=n_running,
+                total_running=total_running, rem_cpu=rem_cpu,
+                rem_mem=rem_mem, rem_io=rem_io, sord=sord, task_of=task_of,
+                qrank=qrank, key_task=key_task, bmin=bmin,
+                start_ctr=start_ctr, rr_i=rr_i, node_of=node_of,
+                start_t_task=start_t_task, cont=cont, it=st.it + 1)
 
         cap_iter = TT + sig.S + 2
         bmin0 = key_task0.reshape(R, NB, _BLOCK).min(axis=2)
-        cont0 = ((n_finished < T)
-                 & more_to_place(free_cores, free_mem, key_task0, bmin0))
-        st = lax.while_loop(
-            lambda st: jnp.any(st[-2]) & (st[-1] < cap_iter), place_body,
-            (free_cores, free_mem, n_running, total_running, rem_cpu,
-             rem_mem, rem_io, sord, task_of, qrank, key_task0, bmin0,
-             start_ctr, rr_i, node_of, start_t_task,
-             jnp.full(R, T, jnp.int32), cont0, 0))
-        (free_cores, free_mem, n_running, total_running, rem_cpu, rem_mem,
-         rem_io, sord, task_of, qrank, key_task, _, start_ctr, rr_i, node_of,
-         start_t_task, jf_last, _, it) = st
-        place_iters = place_iters + it.astype(jnp.int32)
-
-        if sig.fastkey:
-            # restore the (single — uniform demand) failed extraction's key
-            # from its untouched qrank; the dummy row T gather is gated out
-            failedm = jf_last != T
-            kold = (ks.rank_of_names(rank, name_onehot[jf_last]) * shift
-                    + qrank[rr_rows, jf_last])
-            key_task = key_task.at[rr_rows, jf_last].set(
-                jnp.where(failedm, kold, key_task[rr_rows, jf_last]))
+        cont0 = ((c.n_finished < T)
+                 & more_to_place(c.free_cores, c.free_mem, key_task0, bmin0))
+        p = lax.while_loop(
+            lambda st: jnp.any(st.cont) & (st.it < cap_iter), place_body,
+            _Place(free_cores=c.free_cores, free_mem=c.free_mem,
+                   n_running=c.n_running, total_running=c.total_running,
+                   rem_cpu=c.rem_cpu, rem_mem=c.rem_mem, rem_io=c.rem_io,
+                   sord=c.sord, task_of=c.task_of, qrank=qrank,
+                   key_task=key_task0, bmin=bmin0, start_ctr=c.start_ctr,
+                   rr_i=c.rr_i, node_of=c.node_of,
+                   start_t_task=c.start_t_task, cont=cont0, it=0))
+        place_iters = c.place_iters + p.it.astype(jnp.int32)
 
         # ---- next event: earliest finish over active slots (first-min by
         # start ordinal == the engine's append-ordered dense-slot argmin)
-        cpu, mem = ks.node_rates(free_cores, mem_denom_table[n_running],
+        cpu, mem = ks.node_rates(p.free_cores, mem_denom_table[p.n_running],
                                  cpu_base[None, :], mem_base[None, :],
                                  cores_f[None, :], sig.smt_penalty)
-        io_eff = io_seq[None, :] / io_denom_table[total_running][:, None]
-        tl = ks.time_left(rem_cpu, rem_mem, rem_io, cpu, mem, io_eff)
-        active = sord < SENT
+        io_eff = io_seq[None, :] / io_denom_table[p.total_running][:, None]
+        tl = ks.time_left(p.rem_cpu, p.rem_mem, p.rem_io, cpu, mem, io_eff)
+        active = p.sord < SENT
         dt, j_slot = ks.first_min_by_order(
-            tl.reshape(R, sig.S), sord.reshape(R, sig.S),
+            tl.reshape(R, sig.S), p.sord.reshape(R, sig.S),
             active.reshape(R, sig.S))
-        done = n_finished >= T
-        idle = (total_running == 0) & ~done
+        done = c.n_finished >= T
+        idle = (p.total_running == 0) & ~done
         do_fin = ~done & ~idle
 
         if sig.has_arrivals:
@@ -754,26 +766,26 @@ def _scan_program(sig: _Signature):
         else:
             t_new = jnp.where(do_fin, t + dt, t)
 
-        adv = ks.advance(rem_cpu, rem_mem, rem_io, tl, dt)
+        adv = ks.advance(p.rem_cpu, p.rem_mem, p.rem_io, tl, dt)
         g = (do_fin & (dt > 0.0))[:, None, None]
-        rem_cpu = jnp.where(g, adv[0], rem_cpu)
-        rem_mem = jnp.where(g, adv[1], rem_mem)
-        rem_io = jnp.where(g, adv[2], rem_io)
+        rem_cpu = jnp.where(g, adv[0], p.rem_cpu)
+        rem_mem = jnp.where(g, adv[1], p.rem_mem)
+        rem_io = jnp.where(g, adv[2], p.rem_io)
 
         # ---- finish processing: free resources, log end/runtime, ready
         # the dependents (engine: _finish + _on_done)
         n_fin = jnp.where(do_fin, j_slot // CAP, 0)
         c_fin = jnp.where(do_fin, j_slot % CAP, 0)
-        j_task = jnp.where(do_fin, task_of[rr_rows, n_fin, c_fin], T)
-        old_fc = free_cores[rr_rows, n_fin]
-        old_fm = free_mem[rr_rows, n_fin]
-        free_cores = free_cores.at[rr_rows, n_fin].set(
+        j_task = jnp.where(do_fin, p.task_of[rr_rows, n_fin, c_fin], T)
+        old_fc = p.free_cores[rr_rows, n_fin]
+        old_fm = p.free_mem[rr_rows, n_fin]
+        free_cores = p.free_cores.at[rr_rows, n_fin].set(
             jnp.where(do_fin, old_fc + req_cores[j_task], old_fc))
-        free_mem = free_mem.at[rr_rows, n_fin].set(
+        free_mem = p.free_mem.at[rr_rows, n_fin].set(
             jnp.where(do_fin, old_fm + req_mem[j_task], old_fm))
-        n_running = n_running.at[rr_rows, n_fin].add(
+        n_running = p.n_running.at[rr_rows, n_fin].add(
             -do_fin.astype(jnp.int32))
-        total_running = total_running - do_fin.astype(jnp.int32)
+        total_running = p.total_running - do_fin.astype(jnp.int32)
         oldz = lambda a: a[rr_rows, n_fin, c_fin]
         rem_cpu = rem_cpu.at[rr_rows, n_fin, c_fin].set(
             jnp.where(do_fin, 0.0, oldz(rem_cpu)))
@@ -781,17 +793,18 @@ def _scan_program(sig: _Signature):
             jnp.where(do_fin, 0.0, oldz(rem_mem)))
         rem_io = rem_io.at[rr_rows, n_fin, c_fin].set(
             jnp.where(do_fin, 0.0, oldz(rem_io)))
-        sord = sord.at[rr_rows, n_fin, c_fin].set(
-            jnp.where(do_fin, SENT, oldz(sord)))
-        end_t_task = end_t_task.at[rr_rows, j_task].set(
-            jnp.where(do_fin, t_new, end_t_task[rr_rows, j_task]))
-        finish_step = finish_step.at[rr_rows, j_task].set(
-            jnp.where(do_fin, s, finish_step[rr_rows, j_task]))
-        n_finished = n_finished + do_fin.astype(jnp.int32)
+        sord = p.sord.at[rr_rows, n_fin, c_fin].set(
+            jnp.where(do_fin, SENT, oldz(p.sord)))
+        end_t_task = c.end_t_task.at[rr_rows, j_task].set(
+            jnp.where(do_fin, t_new, c.end_t_task[rr_rows, j_task]))
+        finish_step = c.finish_step.at[rr_rows, j_task].set(
+            jnp.where(do_fin, s, c.finish_step[rr_rows, j_task]))
+        n_finished = c.n_finished + do_fin.astype(jnp.int32)
 
+        cnt, sm = c.cnt, c.sm
         if kind == "sjfn":            # TraceDB._runtime_agg, finish order
             kf = jnp.where(do_fin, name_idx[j_task], 0)
-            runtime = t_new - start_t_task[rr_rows, j_task]
+            runtime = t_new - p.start_t_task[rr_rows, j_task]
             cnt = cnt.at[rr_rows, kf].add(jnp.where(do_fin, 1.0, 0.0))
             sm = sm.at[rr_rows, kf].add(jnp.where(do_fin, runtime, 0.0))
 
@@ -806,26 +819,21 @@ def _scan_program(sig: _Signature):
             ready_now = (dl == 0) & (submit_t[depi] <= t_new[:, None])
         else:
             ready_now = dl == 0
-        qr = qrank[rr_rows[:, None], depi]
+        qr = p.qrank[rr_rows[:, None], depi]
         qr = jnp.where(ready_now, (s + 1) * TT + seq[depi], qr)
         dl = jnp.where(ready_now, -1, dl)
         deps_left = deps_left.at[rr_rows[:, None], depi].set(dl)
-        qrank = qrank.at[rr_rows[:, None], depi].set(qr)
-        if sig.fastkey:
-            # stamp the carried key panel too, with this step's ranks — if
-            # next step's ranks differ, the lax.cond above rebuilds anyway
-            kd = ks.rank_of_names(rank, name_onehot[depi]) * shift + qr
-            key_carry = key_task.at[rr_rows[:, None], depi].set(
-                jnp.where(ready_now, kd,
-                          key_task[rr_rows[:, None], depi]))
-        if kind == "sjfn":
-            rank_prev = rank
+        qrank = p.qrank.at[rr_rows[:, None], depi].set(qr)
 
-        return ((t_new, free_cores, free_mem, n_running, total_running,
-                 rem_cpu, rem_mem, rem_io, sord, task_of, qrank,
-                 deps_left, start_ctr, rr_i, cnt, sm, n_finished, node_of,
-                 start_t_task, end_t_task, finish_step,
-                 rank_prev, key_carry, key_rebuilds, place_iters), None)
+        return c._replace(
+            t=t_new, free_cores=free_cores, free_mem=free_mem,
+            n_running=n_running, total_running=total_running,
+            rem_cpu=rem_cpu, rem_mem=rem_mem, rem_io=rem_io, sord=sord,
+            task_of=p.task_of, qrank=qrank, deps_left=deps_left,
+            start_ctr=p.start_ctr, rr_i=p.rr_i, cnt=cnt, sm=sm,
+            n_finished=n_finished, node_of=p.node_of,
+            start_t_task=p.start_t_task, end_t_task=end_t_task,
+            finish_step=finish_step, place_iters=place_iters), None
 
     @jax.jit
     def scan(carry, x):
@@ -864,7 +872,7 @@ def _build_scan(top: _Topology):
         top.sched)
     sig = _Signature(
         top.kind, R, N, CAP, TT, T, K, top.D, top.S, top.n_steps,
-        top.qshift, top.has_arrivals, top.uniform_demand, top.fastkey,
+        top.qshift, top.has_arrivals, top.uniform_demand,
         float(cfg.smt_penalty), float(cfg.io_gamma), float(cfg.mem_beta),
         float(cfg.mem_cap),
         tuple((a.shape, a.dtype.str) for a in host),
@@ -878,33 +886,25 @@ def _build_scan(top: _Topology):
     ready0[T:] = False
     qrank0[:, ready0] = top.seq[ready0]
     deps0[:, ready0] = -1
-    carry0 = (
-        jnp.zeros(R),                                             # t
-        jnp.tile(jnp.asarray(top.cores_f), (R, 1)),               # free_cores
-        jnp.tile(jnp.asarray(top.mem_gb), (R, 1)),                # free_mem
-        jnp.zeros((R, N), jnp.int32),                             # n_running
-        jnp.zeros(R, jnp.int32),                                  # total
-        jnp.zeros((R, N, CAP)), jnp.zeros((R, N, CAP)),
-        jnp.zeros((R, N, CAP)),                                   # rem c/m/io
-        jnp.full((R, N, CAP), _INT_SENTINEL, jnp.int32),          # sord
-        jnp.zeros((R, N, CAP), jnp.int32),                        # task_of
-        jnp.asarray(qrank0),                                      # qrank
-        jnp.asarray(deps0),                                       # deps_left
-        jnp.zeros(R, jnp.int32), jnp.zeros(R, jnp.int32),         # ctr, rr_i
-        jnp.zeros((R, K)), jnp.zeros((R, K)),                     # cnt, sum
-        jnp.zeros(R, jnp.int32),                                  # n_finished
-        jnp.full((R, TT), -1, jnp.int32),                         # node_of
-        jnp.zeros((R, TT)), jnp.zeros((R, TT)),                   # start/end
-        jnp.full((R, TT), -1, jnp.int32),                         # finish_step
-        (jnp.full((R, K), -1, jnp.int32) if top.kind == "sjfn"
-         else jnp.zeros((R, 0), jnp.int32)),                      # rank_prev
-        (jnp.asarray(qrank0) if top.fastkey
-         else jnp.zeros((R, 0), jnp.int32)),                      # key_carry
-        # key_rebuilds: without the fast path every step builds its keys
-        # afresh, so the count starts at the step count and stays there
-        jnp.int32(0 if top.fastkey else top.n_steps),
-        jnp.int32(0),                                             # place_iters
-    )
+    carry0 = _Carry(
+        t=jnp.zeros(R),
+        free_cores=jnp.tile(jnp.asarray(top.cores_f), (R, 1)),
+        free_mem=jnp.tile(jnp.asarray(top.mem_gb), (R, 1)),
+        n_running=jnp.zeros((R, N), jnp.int32),
+        total_running=jnp.zeros(R, jnp.int32),
+        rem_cpu=jnp.zeros((R, N, CAP)), rem_mem=jnp.zeros((R, N, CAP)),
+        rem_io=jnp.zeros((R, N, CAP)),
+        sord=jnp.full((R, N, CAP), _INT_SENTINEL, jnp.int32),
+        task_of=jnp.zeros((R, N, CAP), jnp.int32),
+        qrank=jnp.asarray(qrank0),
+        deps_left=jnp.asarray(deps0),
+        start_ctr=jnp.zeros(R, jnp.int32), rr_i=jnp.zeros(R, jnp.int32),
+        cnt=jnp.zeros((R, K)), sm=jnp.zeros((R, K)),
+        n_finished=jnp.zeros(R, jnp.int32),
+        node_of=jnp.full((R, TT), -1, jnp.int32),
+        start_t_task=jnp.zeros((R, TT)), end_t_task=jnp.zeros((R, TT)),
+        finish_step=jnp.full((R, TT), -1, jnp.int32),
+        place_iters=jnp.int32(0))
     return scan, (carry0, _Inputs(*(jnp.asarray(a) for a in host)))
 
 
@@ -930,13 +930,12 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
     ``ensemble.release`` (dropping the call's device buffers; the program
     stays cached) as ``build_s``, ``compile_s``, ``run_s``, ``fetch_s``
     and ``release_s``; the counts ``compiles`` and ``program_hits`` (one
-    of them 1, the other 0), and the scan's ``n_steps``.  Two counts come
-    from inside the scan: ``key_rebuilds``, the steps on which sjfn's fast
-    path rebuilt its carried key panel (the ``lax.cond`` took
-    ``pack_keys``; every other signature builds its extraction keys afresh
-    on every step, so there it is ``n_steps``, set before the run), and
-    ``place_iters``, the placement ``while_loop``'s iterations summed over
-    the steps (an iteration serves every replica at once)."""
+    of them 1, the other 0), and the scan's ``n_steps``.  Two more count
+    the scan's work: ``key_rebuilds``, the steps on which the extraction
+    keys were built, is ``n_steps``, since every signature builds them
+    afresh on every step; ``place_iters``, counted inside the scan, is the
+    placement ``while_loop``'s iterations summed over the steps (an
+    iteration serves every replica at once)."""
     import jax
 
     rec = tracing.Record()
@@ -957,21 +956,21 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
 
     with rec.span("ensemble.fetch"):
         T = top.T
-        n_fin = np.asarray(out[16])
+        n_fin = np.asarray(out.n_finished)
         if not (n_fin == T).all():
             raise RuntimeError(
                 f"ensemble scan under-ran: {int(n_fin.min())}/{T} finishes "
                 f"within {top.n_steps} steps — step budget bug")
-        end_t = np.asarray(out[19])[:, :T]
-        fstep = np.asarray(out[20])[:, :T]
+        end_t = np.asarray(out.end_t_task)[:, :T]
+        fstep = np.asarray(out.finish_step)[:, :T]
         res = EnsembleResult(
             instances=top.instances, makespan=end_t.max(axis=1),
-            node_idx=np.asarray(out[17])[:, :T].astype(np.int32),
-            start_t=np.asarray(out[18])[:, :T], end_t=end_t,
+            node_idx=np.asarray(out.node_of)[:, :T].astype(np.int32),
+            start_t=np.asarray(out.start_t_task)[:, :T], end_t=end_t,
             finish_order=np.argsort(fstep, axis=1,
                                     kind="stable").astype(np.int32))
-        rec.count("key_rebuilds", int(out[23]))
-        rec.count("place_iters", int(out[24]))
+        rec.count("key_rebuilds", top.n_steps)
+        rec.count("place_iters", int(out.place_iters))
     with rec.span("ensemble.release"):
         del scan, args, compiled, out
     res.timings = {"compiles": 0, "program_hits": 0, **rec.as_dict(),

@@ -264,9 +264,9 @@ def test_scan_module_is_named_jit_scan():
     assert text.startswith("HloModule jit_scan,")
 
 
-@pytest.mark.parametrize("fastkey", [True, False],
-                         ids=["fastkey", "mixed-demand"])
-def test_sjfn_scan_has_no_gather_on_the_rank_table(fastkey):
+@pytest.mark.parametrize("uniform_demand", [True, False],
+                         ids=["uniform-demand", "mixed-demand"])
+def test_sjfn_scan_has_no_gather_on_the_rank_table(uniform_demand):
     """sjfn looks task names up in its [R, K] int32 rank table by a one-hot
     select (``ensemble_step.rank_of_names``): a gather from that table ran
     element by element on the TPU.  The gather's operand types end its
@@ -277,7 +277,7 @@ def test_sjfn_scan_has_no_gather_on_the_rank_table(fastkey):
 
     from repro.workflow import ensemble
 
-    if fastkey:
+    if uniform_demand:
         specs = cluster_5442()
         subs = [Submission(WORKFLOWS["cageseq"](), seed=4, prefix="c"),
                 Submission(WORKFLOWS["eager"](), seed=5, prefix="e")]
@@ -286,7 +286,7 @@ def test_sjfn_scan_has_no_gather_on_the_rank_table(fastkey):
         subs = [Submission(wf, seed=1, prefix="a")]
     top = ensemble._Topology(specs, subs, make_scheduler("sjfn", specs, seed=0),
                              None, 3, 1)
-    assert top.fastkey == fastkey
+    assert top.uniform_demand == uniform_demand
     with jax.enable_x64(True):
         scan, args = ensemble._build_scan(top)
         text = scan.lower(*args).as_text()

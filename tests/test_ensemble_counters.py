@@ -1,7 +1,6 @@
-"""The scan's two counters, ``key_rebuilds`` and ``place_iters``, against
-counts made without the program: a replay of each replica's finishes for
-the first, the placements the result holds for the second."""
-import numpy as np
+"""The call's two work counts, ``key_rebuilds`` and ``place_iters``:
+the first is every step, on every signature; the second is held against
+the placements the result holds."""
 import pytest
 
 from repro.core.scheduler import make_scheduler
@@ -41,55 +40,13 @@ def _run(subs, sched_name, n_replicas):
     return res, top
 
 
-def _name_of_instance(subs):
-    """Each instance's (workflow, task) name, in the topology's order."""
-    return [(s.spec.name, t.name) for s in subs for t in s.spec.tasks
-            for _ in range(t.n_instances)]
-
-
-def recount_key_rebuilds(res, subs, n_steps):
-    """Steps on which any replica's name-rank vector differs from the step
-    before (the first step always counts).  The ranks at step ``k`` come
-    from the per-name mean runtimes of the replica's first ``k`` finishes:
-    without delayed arrivals a replica finishes one task a step."""
-    names = _name_of_instance(subs)
-    keys = sorted(set(names))
-    name_id = np.array([keys.index(n) for n in names])
-    R, T = res.node_idx.shape
-    ranks = np.empty((R, n_steps, len(keys)), np.int64)
-    for r in range(R):
-        cnt = np.zeros(len(keys))
-        total = np.zeros(len(keys))
-        for k in range(n_steps):
-            est = np.full(len(keys), np.inf)
-            seen = cnt > 0
-            est[seen] = total[seen] / cnt[seen]
-            ranks[r, k] = (est[None, :] < est[:, None]).sum(axis=1)
-            if k < T:
-                j = res.finish_order[r, k]
-                cnt[name_id[j]] += 1.0
-                total[name_id[j]] += res.end_t[r, j] - res.start_t[r, j]
-    before = np.concatenate([np.full((R, 1, len(keys)), -1), ranks[:, :-1]],
-                            axis=1)
-    return int((ranks != before).any(axis=(0, 2)).sum())
-
-
-def test_key_rebuilds_match_a_recount_under_sjfn_fast_path():
-    subs = _subs()
-    res, top = _run(subs, "sjfn", 4)
-    assert top.fastkey
-    want = recount_key_rebuilds(res, subs, top.n_steps)
-    assert res.timings["key_rebuilds"] == want
-    assert 0 < want < top.n_steps
-
-
 @pytest.mark.parametrize("sched_name,subs", [
-    ("fair", _subs()), ("fillnodes", _subs()),
+    ("fair", _subs()), ("fillnodes", _subs()), ("sjfn", _subs()),
     ("sjfn", _subs(at=30.0)), ("sjfn", _mixed_demand())],
-    ids=["fair", "fillnodes", "sjfn-arrivals", "sjfn-mixed-demand"])
-def test_key_rebuilds_is_every_step_without_the_fast_path(sched_name, subs):
+    ids=["fair", "fillnodes", "sjfn-uniform", "sjfn-arrivals",
+         "sjfn-mixed-demand"])
+def test_key_rebuilds_is_every_step(sched_name, subs):
     res, top = _run(subs, sched_name, 3)
-    assert not top.fastkey
     assert res.timings["key_rebuilds"] == top.n_steps == res.timings["n_steps"]
 
 
