@@ -1,8 +1,9 @@
 """Fused building blocks of the batched ensemble simulator's scan step.
 
 These are the hot inner expressions of ``repro.workflow.ensemble`` — the
-per-event node-rate / time-left / advance math and the masked first-min
-argmin reductions — kept here so they can be unit-tested against their
+per-event node-rate / time-left / advance math, the masked first-min
+argmin reductions and the one-hot reads and writes of per-replica panels —
+kept here so they can be unit-tested against their
 numpy twins in ``engine.py`` / ``allocation.py`` and reused by future
 fleet-scale consumers (ROADMAP items 2/5 want exactly these primitives).
 
@@ -147,6 +148,58 @@ def rank_of_names(rank, onehot):
     R, K = rank.shape
     r = rank.reshape(R, *(1,) * (onehot.ndim - 2), K)
     return jnp.sum(jnp.where(onehot, r, 0), axis=-1, dtype=jnp.int32)
+
+
+def _hot(a, idx):
+    """The bool mask True where the k axes of ``a`` after the first equal
+    ``idx``, shaped [R, a.shape[1], ..., a.shape[k], 1, ...] to broadcast
+    against ``a``; and k.  ``a``'s first axis is the replica axis, or of
+    size 1 for a panel that every replica shares."""
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    hot = True
+    for ax, i in enumerate(idx):
+        iota = jnp.arange(a.shape[1 + ax], dtype=i.dtype).reshape(
+            (1,) * (1 + ax) + (-1,) + (1,) * (a.ndim - 2 - ax))
+        hot = hot & (iota == i.reshape((-1,) + (1,) * (a.ndim - 1)))
+    return hot, len(idx)
+
+
+def take_one_hot(a, idx):
+    """``a[r, idx[r]]`` for every replica r by a one-hot select and a
+    reduction, no gather.
+
+    a: [R, W, ...], or [1, W, ...] for a panel every replica shares; idx:
+    an [R] int array, or a tuple of k of them that index the k axes after
+    the first.  Returns [R, ...] (the axes not indexed).  Exact, each
+    reduction having one selected term: integers by ``sum``, floats by
+    ``max`` over ``-inf`` elsewhere (an emulated f64 add is not IEEE on a
+    TPU; a max is), so ``-0.0`` and ``inf`` come back as stored.
+
+    A batched point gather runs element by element on a TPU; the select
+    fuses into its reduction and streams the panel once.
+    """
+    hot, k = _hot(a, idx)
+    axes = tuple(range(1, 1 + k))
+    if jnp.issubdtype(a.dtype, jnp.integer):
+        return jnp.sum(jnp.where(hot, a, 0), axis=axes, dtype=a.dtype)
+    return jnp.max(jnp.where(hot, a, -jnp.inf), axis=axes)
+
+
+def put_one_hot(a, idx, gate, value):
+    """``a`` with ``a[r, idx[r]] = value[r]`` where ``gate[r]``, by a masked
+    select, no scatter: a gated-off replica's row is left as it is.
+
+    a, idx as in :func:`take_one_hot`; gate: [R] bool; value: a scalar, an
+    [R, ...] array of the shape the index leaves (one value per replica),
+    or an array of ``a``'s own shape (``a - x`` writes ``a[r, idx] - x``).
+    The value is cast to ``a``'s dtype, as a scatter casts it.
+    """
+    hot, k = _hot(a, idx)
+    hot = hot & gate.reshape((-1,) + (1,) * (a.ndim - 1))
+    v = jnp.asarray(value, a.dtype)
+    if 0 < v.ndim < a.ndim:
+        v = v.reshape(v.shape[:1] + (1,) * k + v.shape[1:])
+    return jnp.where(hot, v, a)
 
 
 def node_load(free_cores, free_mem, cores, mem_gb):

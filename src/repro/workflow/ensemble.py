@@ -256,8 +256,8 @@ class _Topology:
         self.K = len(name_keys)
         T = len(ids)
         self.T = T
-        # dummy row T absorbs masked scatters; pad to an argmin block multiple
-        self.TT = ((T + 1 + _BLOCK - 1) // _BLOCK) * _BLOCK
+        # pad to an argmin block multiple
+        self.TT = -(-T // _BLOCK) * _BLOCK
 
         self.name_idx = np.zeros(self.TT, np.int32)
         self.base_work = np.zeros((T, 3), np.float64)
@@ -265,7 +265,7 @@ class _Topology:
         self.req_mem = np.zeros(self.TT, np.float64)
         self.submit_t = np.full(self.TT, np.inf)
         deps_n = np.zeros(self.TT, np.int32)
-        deps_n[T:] = 1 << 20                      # dummy rows never promote
+        deps_n[T:] = 1 << 20                      # pad rows never promote
         dependents: list = [[] for _ in range(self.TT)]
         for j, (nk, w3, rc, rm, deps) in enumerate(rows):
             self.name_idx[j] = nk
@@ -279,7 +279,7 @@ class _Topology:
             self.submit_t[lo:hi] = sub.at
         self.deps_left0 = deps_n
         self.D = max(1, max(len(d) for d in dependents))
-        self.dependents = np.full((self.TT, self.D), T, np.int32)  # pad=dummy
+        self.dependents = np.full((self.TT, self.D), -1, np.int32)  # pad -1
         for j, dl in enumerate(dependents):
             self.dependents[j, :len(dl)] = dl
         self.seq = np.arange(self.TT, dtype=np.int32)
@@ -376,7 +376,7 @@ class _Inputs(NamedTuple):
     req_mem: object            # [TT] f64
     submit_t: object           # [TT] f64
     name_idx: object           # [TT] int32
-    dependents: object         # [TT, D] int32
+    dependents: object         # [TT, D] int32, -1 past a task's last
     work_cpu: object           # [R, TT] f64, this call's draws
     work_mem: object           # [R, TT] f64
     work_io: object            # [R, TT] f64
@@ -423,7 +423,8 @@ class _Place(NamedTuple):
     sord: object
     task_of: object
     qrank: object
-    key_task: object           # [R, TT] int32 extraction key, SENT if tried
+    key_task: object           # [R, TT//_BLOCK, _BLOCK] int32 extraction
+                               # key in blocks, SENT once tried
     bmin: object               # [R, TT // _BLOCK] int32 block minima
     start_ctr: object
     rr_i: object
@@ -525,7 +526,7 @@ def _scan_program(sig: _Signature):
     R, N, CAP, TT, T = sig.R, sig.N, sig.CAP, sig.TT, sig.T
     K, kind = sig.K, sig.kind
     SENT = jnp.int32(_INT_SENTINEL)
-    rr_rows = jnp.arange(R, dtype=jnp.int32)
+    take, put = ks.take_one_hot, ks.put_one_hot
 
     # Arrays that depend only on the signature are trace-time constants:
     # the task slots' promotion ordinals and the contention denominators.
@@ -611,14 +612,28 @@ def _scan_program(sig: _Signature):
             key_task0 = qrank
 
         # Extraction is a two-level min: per-block minima (bmin, [R, NB])
-        # are carried through the loop and only the winning block's 64-wide
-        # row is rescanned after an update, so one iteration touches
-        # O(R·(NB+B)) keys instead of the full [R, TT] panel — the flat
-        # argmin was the single largest cost of the whole step.  First-min
-        # semantics (lowest index wins ties) are preserved: argmin over
-        # block minima picks the first block holding the global min, then
-        # the first slot inside it — ``ks.blocked_argmin_i32`` exactly.
+        # are carried through the loop beside the keys in blocks
+        # ([R, NB, _BLOCK]), and after an update only the winning block's
+        # minimum is recomputed, from its 64-wide row.  First-min semantics
+        # (lowest index wins ties) are preserved: argmin over block minima
+        # picks the first block holding the global min, then the first slot
+        # inside it — ``ks.blocked_argmin_i32`` exactly.
+        #
+        # Every per-replica read or write of a panel here is a one-hot
+        # masked select (``take`` / ``put``), not a point gather or
+        # scatter, which a TPU runs element by element, so that its cost
+        # grew with R.  A gated-off replica changes nothing.
         NB = TT // _BLOCK
+
+        def extract(key_task, bmin):
+            """The least key's block ``b``, that block's row of keys, the
+            slot ``within`` it and the task ``j``, with j's demand."""
+            b = jnp.argmin(bmin, axis=1).astype(jnp.int32)
+            rows = take(key_task, b)                  # [R, _BLOCK]
+            within = jnp.argmin(rows, axis=1).astype(jnp.int32)
+            j = b * _BLOCK + within
+            return (b, rows, within, j, take(req_cores[None], j),
+                    take(req_mem[None], j))
 
         def more_to_place(free_cores, free_mem, key_task, bmin):
             # Lookahead twin of the loop's own extract-and-test: True iff
@@ -628,25 +643,18 @@ def _scan_program(sig: _Signature):
             # Evaluating this at the *end* of each iteration (instead of
             # ``cont = place | ...``) means the loop exits without the
             # steady-state extra body run whose only product was
-            # discovering that the cluster is full — that run still paid
-            # for a full select_node and every (dummy) placement scatter.
-            b = jnp.argmin(bmin, axis=1).astype(jnp.int32)
-            rows = jnp.take_along_axis(key_task.reshape(R, NB, _BLOCK),
-                                       b[:, None, None], axis=1)[:, 0, :]
-            within = jnp.argmin(rows, axis=1).astype(jnp.int32)
-            j = b * _BLOCK + within
-            has = rows[rr_rows, within] < SENT
-            rc = req_cores[j]
-            rm = req_mem[j]
+            # discovering that the cluster is full.
+            _, rows, _, _, rc, rm = extract(key_task, bmin)
+            has = jnp.min(rows, axis=1) < SENT
             any_feas = ((free_cores >= rc[:, None])
                         & (free_mem >= rm[:, None])).any(axis=1)
             if sig.uniform_demand:
                 return has & any_feas
             left = key_task < SENT
-            min_rc = jnp.min(jnp.where(left, req_cores[None, :], jnp.inf),
-                             axis=1)
-            min_rm = jnp.min(jnp.where(left, req_mem[None, :], jnp.inf),
-                             axis=1)
+            min_rc = jnp.min(jnp.where(left, req_cores.reshape(NB, _BLOCK),
+                                       jnp.inf), axis=(1, 2))
+            min_rm = jnp.min(jnp.where(left, req_mem.reshape(NB, _BLOCK),
+                                       jnp.inf), axis=(1, 2))
             fitmin = ((free_cores >= min_rc[:, None])
                       & (free_mem >= min_rm[:, None])).any(axis=1)
             # a candidate that fails in-body is retired before the
@@ -656,15 +664,8 @@ def _scan_program(sig: _Signature):
             return has & (any_feas | fitmin)
 
         def place_body(st):
-            b = jnp.argmin(st.bmin, axis=1).astype(jnp.int32)
-            rows = jnp.take_along_axis(st.key_task.reshape(R, NB, _BLOCK),
-                                       b[:, None, None], axis=1)[:, 0, :]
-            within = jnp.argmin(rows, axis=1).astype(jnp.int32)
-            j = b * _BLOCK + within
-            kmin = rows[rr_rows, within]
-            has_task = (kmin < SENT) & st.cont
-            rc = req_cores[j]
-            rm = req_mem[j]
+            b, rows, within, j, rc, rm = extract(st.key_task, st.bmin)
+            has_task = (jnp.min(rows, axis=1) < SENT) & st.cont
             feas = ((st.free_cores >= rc[:, None])
                     & (st.free_mem >= rm[:, None]))
             any_feas = feas.any(axis=1)
@@ -672,48 +673,31 @@ def _scan_program(sig: _Signature):
             fail = has_task & ~any_feas
             n_sel, rr_pos_sel = select_node(feas, st.free_cores, st.free_mem,
                                             st.rr_i, x)
-            # retire a failed extraction (the engine appends to `still`;
-            # its suffix-min blocked check lives in ``more_to_place``)
-            jf = jnp.where(fail, j, T)
-            key_task = st.key_task.at[rr_rows, jf].set(
-                jnp.where(fail, SENT, st.key_task[rr_rows, jf]))
-            # apply the placement (per-replica gated scatters; dummies
-            # target task row T / node 0 and rewrite the existing value)
-            jp = jnp.where(place, j, T)
-            npl = jnp.where(place, n_sel, 0)
-            c_sel = jnp.argmax(st.sord[rr_rows, npl] == SENT, axis=1)
-            old_fc = st.free_cores[rr_rows, npl]
-            old_fm = st.free_mem[rr_rows, npl]
-            free_cores = st.free_cores.at[rr_rows, npl].set(
-                jnp.where(place, old_fc - rc, old_fc))
-            free_mem = st.free_mem.at[rr_rows, npl].set(
-                jnp.where(place, old_fm - rm, old_fm))
-            n_running = st.n_running.at[rr_rows, npl].add(
-                place.astype(jnp.int32))
-            total_running = st.total_running + place.astype(jnp.int32)
-            old = lambda a: a[rr_rows, npl, c_sel]
-            rem_cpu = st.rem_cpu.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, work_cpu[rr_rows, jp], old(st.rem_cpu)))
-            rem_mem = st.rem_mem.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, work_mem[rr_rows, jp], old(st.rem_mem)))
-            rem_io = st.rem_io.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, work_io[rr_rows, jp], old(st.rem_io)))
-            sord = st.sord.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, st.start_ctr, old(st.sord)))
-            task_of = st.task_of.at[rr_rows, npl, c_sel].set(
-                jnp.where(place, j, old(st.task_of)))
-            qrank = st.qrank.at[rr_rows, jp].set(
-                jnp.where(place, SENT, st.qrank[rr_rows, jp]))
-            key_task = key_task.at[rr_rows, jp].set(
-                jnp.where(place, SENT, key_task[rr_rows, jp]))
+            # retire the extracted key, placed or failed (a failed one is
+            # the engine's append to `still`; its suffix-min blocked check
+            # lives in ``more_to_place``)
             retired = place | fail
-            rows = rows.at[rr_rows, within].set(
-                jnp.where(retired, SENT, kmin))
-            bmin = st.bmin.at[rr_rows, b].set(jnp.min(rows, axis=1))
-            node_of = st.node_of.at[rr_rows, jp].set(
-                jnp.where(place, n_sel, st.node_of[rr_rows, jp]))
-            start_t_task = st.start_t_task.at[rr_rows, jp].set(
-                jnp.where(place, t, st.start_t_task[rr_rows, jp]))
+            key_task = put(st.key_task, (b, within), retired, SENT)
+            rows = put(rows, within, retired, SENT)
+            bmin = put(st.bmin, b, retired, jnp.min(rows, axis=1))
+            # apply the placement to the chosen node's first free slot
+            c_sel = jnp.argmax(take(st.sord, n_sel) == SENT,
+                               axis=1).astype(jnp.int32)
+            slot = (n_sel, c_sel)
+            free_cores = put(st.free_cores, n_sel, place,
+                             st.free_cores - rc[:, None])
+            free_mem = put(st.free_mem, n_sel, place,
+                           st.free_mem - rm[:, None])
+            n_running = put(st.n_running, n_sel, place, st.n_running + 1)
+            total_running = st.total_running + place.astype(jnp.int32)
+            rem_cpu = put(st.rem_cpu, slot, place, take(work_cpu, j))
+            rem_mem = put(st.rem_mem, slot, place, take(work_mem, j))
+            rem_io = put(st.rem_io, slot, place, take(work_io, j))
+            sord = put(st.sord, slot, place, st.start_ctr)
+            task_of = put(st.task_of, slot, place, j)
+            qrank = put(st.qrank, j, place, SENT)
+            node_of = put(st.node_of, j, place, n_sel)
+            start_t_task = put(st.start_t_task, j, place, t)
             start_ctr = st.start_ctr + place.astype(jnp.int32)
             rr_i = st.rr_i
             if kind == "roundrobin":
@@ -728,16 +712,17 @@ def _scan_program(sig: _Signature):
                 start_t_task=start_t_task, cont=cont, it=st.it + 1)
 
         cap_iter = TT + sig.S + 2
-        bmin0 = key_task0.reshape(R, NB, _BLOCK).min(axis=2)
+        key_blocks0 = key_task0.reshape(R, NB, _BLOCK)
+        bmin0 = key_blocks0.min(axis=2)
         cont0 = ((c.n_finished < T)
-                 & more_to_place(c.free_cores, c.free_mem, key_task0, bmin0))
+                 & more_to_place(c.free_cores, c.free_mem, key_blocks0, bmin0))
         p = lax.while_loop(
             lambda st: jnp.any(st.cont) & (st.it < cap_iter), place_body,
             _Place(free_cores=c.free_cores, free_mem=c.free_mem,
                    n_running=c.n_running, total_running=c.total_running,
                    rem_cpu=c.rem_cpu, rem_mem=c.rem_mem, rem_io=c.rem_io,
                    sord=c.sord, task_of=c.task_of, qrank=qrank,
-                   key_task=key_task0, bmin=bmin0, start_ctr=c.start_ctr,
+                   key_task=key_blocks0, bmin=bmin0, start_ctr=c.start_ctr,
                    rr_i=c.rr_i, node_of=c.node_of,
                    start_t_task=c.start_t_task, cont=cont0, it=0))
         place_iters = c.place_iters + p.it.astype(jnp.int32)
@@ -773,58 +758,45 @@ def _scan_program(sig: _Signature):
         rem_io = jnp.where(g, adv[2], p.rem_io)
 
         # ---- finish processing: free resources, log end/runtime, ready
-        # the dependents (engine: _finish + _on_done)
-        n_fin = jnp.where(do_fin, j_slot // CAP, 0)
-        c_fin = jnp.where(do_fin, j_slot % CAP, 0)
-        j_task = jnp.where(do_fin, p.task_of[rr_rows, n_fin, c_fin], T)
-        old_fc = p.free_cores[rr_rows, n_fin]
-        old_fm = p.free_mem[rr_rows, n_fin]
-        free_cores = p.free_cores.at[rr_rows, n_fin].set(
-            jnp.where(do_fin, old_fc + req_cores[j_task], old_fc))
-        free_mem = p.free_mem.at[rr_rows, n_fin].set(
-            jnp.where(do_fin, old_fm + req_mem[j_task], old_fm))
-        n_running = p.n_running.at[rr_rows, n_fin].add(
-            -do_fin.astype(jnp.int32))
+        # the dependents (engine: _finish + _on_done); every write is gated
+        # by do_fin, so a replica that finishes nothing reads a slot and a
+        # task it leaves alone
+        fin = (j_slot // CAP, j_slot % CAP)
+        j_task = take(p.task_of, fin)
+        free_cores = put(
+            p.free_cores, fin[0], do_fin,
+            p.free_cores + take(req_cores[None], j_task)[:, None])
+        free_mem = put(
+            p.free_mem, fin[0], do_fin,
+            p.free_mem + take(req_mem[None], j_task)[:, None])
+        n_running = put(p.n_running, fin[0], do_fin, p.n_running - 1)
         total_running = p.total_running - do_fin.astype(jnp.int32)
-        oldz = lambda a: a[rr_rows, n_fin, c_fin]
-        rem_cpu = rem_cpu.at[rr_rows, n_fin, c_fin].set(
-            jnp.where(do_fin, 0.0, oldz(rem_cpu)))
-        rem_mem = rem_mem.at[rr_rows, n_fin, c_fin].set(
-            jnp.where(do_fin, 0.0, oldz(rem_mem)))
-        rem_io = rem_io.at[rr_rows, n_fin, c_fin].set(
-            jnp.where(do_fin, 0.0, oldz(rem_io)))
-        sord = p.sord.at[rr_rows, n_fin, c_fin].set(
-            jnp.where(do_fin, SENT, oldz(p.sord)))
-        end_t_task = c.end_t_task.at[rr_rows, j_task].set(
-            jnp.where(do_fin, t_new, c.end_t_task[rr_rows, j_task]))
-        finish_step = c.finish_step.at[rr_rows, j_task].set(
-            jnp.where(do_fin, s, c.finish_step[rr_rows, j_task]))
+        rem_cpu = put(rem_cpu, fin, do_fin, 0.0)
+        rem_mem = put(rem_mem, fin, do_fin, 0.0)
+        rem_io = put(rem_io, fin, do_fin, 0.0)
+        sord = put(p.sord, fin, do_fin, SENT)
+        end_t_task = put(c.end_t_task, j_task, do_fin, t_new)
+        finish_step = put(c.finish_step, j_task, do_fin, s)
         n_finished = c.n_finished + do_fin.astype(jnp.int32)
 
         cnt, sm = c.cnt, c.sm
         if kind == "sjfn":            # TraceDB._runtime_agg, finish order
-            kf = jnp.where(do_fin, name_idx[j_task], 0)
-            runtime = t_new - p.start_t_task[rr_rows, j_task]
-            cnt = cnt.at[rr_rows, kf].add(jnp.where(do_fin, 1.0, 0.0))
-            sm = sm.at[rr_rows, kf].add(jnp.where(do_fin, runtime, 0.0))
+            kf = take(name_idx[None], j_task)
+            runtime = t_new - take(p.start_t_task, j_task)
+            cnt = put(cnt, kf, do_fin, cnt + 1.0)
+            sm = put(sm, kf, do_fin, sm + runtime[:, None])
 
-        # ---- dependent scatter: decrement counters; newly-ready tasks get
-        # next step's batch base (duplicate dummy targets all rewrite the
-        # same gathered value, so the scatter stays deterministic)
-        depi = dependents[j_task]                                # [R, D]
-        real = depi != T
-        dl = deps_left[rr_rows[:, None], depi] \
-            - (do_fin[:, None] & real).astype(jnp.int32)
+        # ---- dependents: decrement the finished task's dependents'
+        # counters; newly-ready tasks get next step's batch base.  A task
+        # listed twice among one task's dependents is decremented once.
+        depi = take(dependents[None], j_task)        # [R, D]
+        hit = do_fin[:, None] & jnp.any(depi[:, :, None] == seq, axis=1)
+        dl = deps_left - hit.astype(jnp.int32)
+        ready_now = hit & (dl == 0)
         if sig.has_arrivals:
-            ready_now = (dl == 0) & (submit_t[depi] <= t_new[:, None])
-        else:
-            ready_now = dl == 0
-        qr = p.qrank[rr_rows[:, None], depi]
-        qr = jnp.where(ready_now, (s + 1) * TT + seq[depi], qr)
-        dl = jnp.where(ready_now, -1, dl)
-        deps_left = deps_left.at[rr_rows[:, None], depi].set(dl)
-        qrank = p.qrank.at[rr_rows[:, None], depi].set(qr)
-
+            ready_now = ready_now & (submit_t[None, :] <= t_new[:, None])
+        qrank = jnp.where(ready_now, (s + 1) * TT + seq[None, :], p.qrank)
+        deps_left = jnp.where(ready_now, -1, dl)
         return c._replace(
             t=t_new, free_cores=free_cores, free_mem=free_mem,
             n_running=n_running, total_running=total_running,

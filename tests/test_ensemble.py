@@ -10,6 +10,8 @@ refuse loudly at build time, never silently diverge.
 Runs through the ``tests/_hyp.py`` shim (deterministic fallback when
 hypothesis isn't installed).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st
@@ -116,7 +118,6 @@ def test_scan_replica_seeds_match_individual_engine_runs():
 
 def test_compare_traces_separates_decisions_from_times():
     """The TPU contract's measure: decisions exact, times to a tolerance."""
-    import dataclasses
     specs = cluster_555()
     subs = [Submission(WORKFLOWS["mag"](), seed=3)]
     ref = oracle_ensemble(specs, subs, make_scheduler("fair", specs, seed=0),
@@ -293,6 +294,81 @@ def test_sjfn_scan_has_no_gather_on_the_rank_table(uniform_demand):
     operands = re.findall(r'"stablehlo\.gather".*: \((tensor<[^>]*>)', text)
     assert operands                       # the pattern reads the other gathers
     assert f"tensor<{top.n_replicas}x{top.K}xi32>" not in operands
+
+
+_SCATTER_OPERAND = r'"stablehlo\.scatter"\(.*?\}\) : \((tensor<[^>]*>)'
+
+
+@pytest.mark.parametrize("sched_name", ["fair", "sjfn"])
+def test_scan_has_no_scatter_into_slot_or_task_panels(sched_name):
+    """Every per-replica write of the scan's step into an [R, N, CAP] slot
+    panel or an [R, TT] task panel is a one-hot masked select
+    (``ensemble_step.put_one_hot``): a point scatter runs element by
+    element on a TPU, so its cost grew with R.  A scatter's operand types
+    follow its update region in the lowered text, the panel's first."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.workflow import ensemble
+
+    specs = cluster_5442()
+    subs = [Submission(WORKFLOWS["cageseq"](), seed=4, prefix="c"),
+            Submission(WORKFLOWS["eager"](), seed=5, prefix="e")]
+    top = ensemble._Topology(specs, subs,
+                             make_scheduler(sched_name, specs, seed=0),
+                             None, 3, 1)
+    R, N, CAP, TT = top.n_replicas, top.N, top.CAP, top.TT
+    with jax.enable_x64(True):
+        scan, args = ensemble._build_scan(top)
+        text = scan.lower(*args).as_text()
+        # the pattern reads the panel of a point scatter
+        point = jax.jit(lambda a, i: a.at[jnp.arange(R), i].set(1.0)).lower(
+            jnp.zeros((R, TT)), jnp.zeros(R, jnp.int32)).as_text()
+    assert re.findall(_SCATTER_OPERAND, point, re.S) == [f"tensor<{R}x{TT}xf64>"]
+    panels = {f"tensor<{R}x{shape}x{t}>" for shape in (f"{N}x{CAP}", TT)
+              for t in ("f64", "i32")}
+    assert not panels & set(re.findall(_SCATTER_OPERAND, text, re.S))
+
+
+def _uniform(wf):
+    return WorkflowSpec(wf.name, [dataclasses.replace(t, req_cores=2,
+                                                      req_mem_gb=4.0)
+                                  for t in wf.tasks])
+
+
+@pytest.mark.parametrize("case", ["uniform", "arrivals", "mixed"])
+@pytest.mark.parametrize("sched_name", _SCHEDS)
+def test_scan_matches_engine_with_replicas_gated_apart(sched_name, case):
+    """Three replicas of a contended cluster, so that in one placement
+    iteration some replicas place while others fail or are done, and in one
+    step some finish while others idle: the gated writes must leave the
+    gated-off replicas' panels exactly as the engine has them.  Every
+    scheduler under uniform demand, under delayed arrivals and under mixed
+    demand."""
+    from repro.workflow import ensemble
+
+    rng = np.random.default_rng(11)
+    specs = random_cluster(rng)
+    wfs = [random_workflow(rng, "wfa"), random_workflow(rng, "wfb")]
+    if case != "mixed":
+        wfs = [_uniform(wf) for wf in wfs]
+    at = 30.0 if case == "arrivals" else 0.0
+    subs = [Submission(wfs[0], seed=1, prefix="a"),
+            Submission(wfs[1], seed=2, at=at, prefix="b")]
+    top = ensemble._Topology(specs, subs,
+                             make_scheduler(sched_name, specs, seed=0),
+                             None, 3, 7)
+    assert (top.uniform_demand, top.has_arrivals) == (
+        case != "mixed", case == "arrivals")
+    res = run_ensemble(specs, subs, make_scheduler(sched_name, specs, seed=0),
+                       n_replicas=3, seed_stride=7)
+    ref = oracle_ensemble(specs, subs,
+                          make_scheduler(sched_name, specs, seed=0),
+                          n_replicas=3, seed_stride=7)
+    assert_equivalent(res, ref)
+    assert len(set(res.makespan)) == 3
 
 
 # ------------------------------------------------------- program reuse

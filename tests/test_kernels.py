@@ -213,3 +213,58 @@ def test_ensemble_rank_of_names_matches_fancy_indexing(R, TT, K, form):
     got = ks.rank_of_names(jnp.asarray(rank), jnp.asarray(onehot))
     assert got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("shape", [(3, 64), (256, 320), (64, 2176),
+                                   (3, 5, 4), (256, 15, 8), (64, 256, 4)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", [np.float64, np.int32])
+def test_ensemble_one_hot_take_and_put_match_fancy_indexing(shape, dtype):
+    """``take_one_hot`` is ``a[r, idx[r]]`` and ``put_one_hot`` is a gated
+    ``a[r, idx[r]] = v[r]``, bit for bit, over [R, W] task panels and
+    [R, N, CAP] slot panels (the forecast cells' shapes and a small one):
+    rows gated off are left as they are, the last position is reachable,
+    and f64 ``-0.0`` and ``inf`` read back as stored."""
+    from repro.kernels import ensemble_step as ks
+    take, put = ks.take_one_hot, ks.put_one_hot
+    rng = np.random.default_rng(sum(shape))
+    R, rest = shape[0], shape[1:]
+    if dtype == np.float64:
+        a = rng.standard_normal(shape)
+        value = rng.standard_normal(R)
+        value[:2] = [-0.0, np.inf]
+    else:
+        a = rng.integers(-(1 << 30), 1 << 30, shape).astype(np.int32)
+        value = rng.integers(-(1 << 30), 1 << 30, R).astype(np.int32)
+    idx = tuple(rng.integers(0, n, R).astype(np.int32) for n in rest)
+    for i, n in zip(idx, rest):
+        i[0], i[-1] = n - 1, 0                    # the last and first slots
+    rows = np.arange(R)
+    a[(rows[:3],) + tuple(i[:3] for i in idx)] = np.asarray(
+        [-0.0, np.inf, -np.inf] if dtype == np.float64 else [0, -1, 1 << 30],
+        dtype)
+    gate = rng.random(R) < 0.5
+    gate[:3] = True, True, False
+    bits = (lambda x: x.view(np.int64)) if dtype == np.float64 else np.asarray
+    with jax.enable_x64(True):
+        ja, jidx = jnp.asarray(a), tuple(jnp.asarray(i) for i in idx)
+        got = np.asarray(take(ja, jidx))
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(bits(got), bits(a[(rows,) + idx]))
+        # a panel every replica shares
+        shared = np.asarray(take(ja[:1], jidx))
+        np.testing.assert_array_equal(
+            bits(shared), bits(a[(np.zeros(R, int),) + idx]))
+        # the leading axes alone: a row of the slot panel per replica
+        got_row = np.asarray(take(ja, jidx[0]))
+        np.testing.assert_array_equal(bits(got_row), bits(a[rows, idx[0]]))
+        out = np.asarray(put(ja, jidx, jnp.asarray(gate), jnp.asarray(value)))
+        # a value of the panel's own shape writes its own entries
+        out_full = np.asarray(put(ja, jidx, jnp.asarray(gate), ja + ja))
+    at = (rows[gate],) + tuple(i[gate] for i in idx)
+    want = a.copy()
+    want[at] = value[gate]
+    np.testing.assert_array_equal(bits(out), bits(want))
+    want = a.copy()
+    want[at] = (a + a)[at]
+    np.testing.assert_array_equal(bits(out_full), bits(want))
