@@ -119,9 +119,10 @@ def _bench_once(engine_mod, sched_name: str, n_nodes: int, n_instances: int,
     # per-phase attribution (vectorized engine only): scheduling wall vs
     # event-loop wall vs monitor-ingest wall, so a future regression is
     # attributable to the layer that caused it
-    phases = getattr(eng, "phase_wall", None)
-    if phases:
-        rec["phase_wall_s"] = {k: round(v, 3) for k, v in phases.items()}
+    timings = res.get("timings")
+    if timings:
+        rec["phase_wall_s"] = {k: round(timings[k], 3)
+                               for k in ("schedule_s", "monitor_s", "event_s")}
     return rec
 
 

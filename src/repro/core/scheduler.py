@@ -28,6 +28,7 @@ from collections import defaultdict
 
 import numpy as np
 
+from repro import tracing
 from repro.core import allocation, labeling
 from repro.core.clustering import choose_k
 from repro.core.monitor import TraceDB
@@ -187,15 +188,30 @@ class _ProfiledScheduler(Scheduler):
     ``profiles`` overrides the synthetic phase-1 benchmarks with externally
     *measured* ones (the real-execution backend profiles its nodes via
     ``profile_local`` / ``selfhost.profile_backend``); grouping/labeling
-    are identical either way.  One profile per spec, same node names."""
+    are identical either way.  One profile per spec, same node names.
+
+    ``timings`` is phase 1's ``tracing.Record``: the spans
+    ``tarema.profile``, ``tarema.group`` (``choose_k``, whose own record is
+    kept under ``timings["grouping"]``) and ``tarema.info`` as
+    ``profile_s``, ``group_s`` and ``info_s``.  ``counts`` holds the
+    scheduler's running counts, which ``Engine.run`` reports per run:
+    ``label_computes`` (misses of the label memo)."""
 
     def __init__(self, specs, seed: int = 0,
                  profiles: list[NodeProfile] | None = None):
-        self.profiles: list[NodeProfile] = list(profiles) \
-            if profiles is not None else profile_cluster_synthetic(specs, seed)
-        X = np.stack([p.vector() for p in self.profiles])
-        self.grouping = choose_k(X, k_max=6)
-        self.info = labeling.build_group_info(self.profiles, self.grouping["labels"])
+        rec = tracing.Record()
+        with rec.span("tarema.profile"):
+            self.profiles: list[NodeProfile] = list(profiles) \
+                if profiles is not None \
+                else profile_cluster_synthetic(specs, seed)
+        with rec.span("tarema.group"):
+            X = np.stack([p.vector() for p in self.profiles])
+            self.grouping = choose_k(X, k_max=6)
+        with rec.span("tarema.info"):
+            self.info = labeling.build_group_info(self.profiles,
+                                                  self.grouping["labels"])
+        self.timings = {**rec.as_dict(), "grouping": self.grouping["timings"]}
+        self.counts = {"label_computes": 0}
         # fastest-first node order by measured cpu speed (for SJFN)
         self.by_speed = [p.node for p in
                          sorted(self.profiles, key=lambda p: -p.features["cpu"])]
@@ -214,6 +230,7 @@ class _ProfiledScheduler(Scheduler):
         if key not in self._label_cache:
             if len(self._label_cache) > 65536:     # epoch churn backstop
                 self._label_cache.clear()
+            self.counts["label_computes"] += 1
             self._label_cache[key] = labeling.label_task(
                 db, self.info, workflow, task_name)
         return self._label_cache[key]
@@ -289,11 +306,14 @@ class TaremaScheduler(_ProfiledScheduler):
         super().__init__(specs, seed, profiles)
         self.rng = np.random.default_rng(seed + 1)
         self._priority_cache: dict = {}  # label vector -> group priority list
+        # calls of allocation.task_scores, each one device round trip
+        self.counts["score_dispatches"] = 0
 
     def _cached_priority(self, labels) -> list:
         key = tuple(sorted(labels.items()))
         priority = self._priority_cache.get(key)
         if priority is None:
+            self.counts["score_dispatches"] += 1
             priority = allocation.priority_groups(self.info, labels)
             self._priority_cache[key] = priority
         return priority
@@ -402,6 +422,7 @@ class WeightedTaremaScheduler(TaremaScheduler):
         key = tuple(sorted(labels.items()))
         base = self._scores_cache.get(key)
         if base is None:
+            self.counts["score_dispatches"] += 1
             base = allocation.task_scores(self.info, labels)
             self._scores_cache[key] = base
         return allocation.weighted_priority_groups(

@@ -52,7 +52,8 @@ class Record:
                 self.count(count)
 
     def count(self, name: str, n: int = 1) -> None:
-        """Add ``n`` to the record's count of ``name``."""
+        """Add ``n`` to the record's total ``name``: a count, or seconds
+        timed without a span where a span per event would cost too much."""
         self.totals[name] = self.totals.get(name, 0) + n
 
     def as_dict(self) -> dict:

@@ -96,6 +96,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.fairness import AssignmentRecord
 from repro.core.monitor import TaskTrace, TraceDB
 from repro.core.prediction import (PredictionConfig, PredictionRecord,
@@ -394,10 +395,9 @@ class Engine:
         # array-native placement state (decided per run in _prepare)
         self._use_array = False
         self._mask_cache: dict[tuple, np.ndarray] = {}
-        # per-phase wall-clock accounting (see engine_bench breakdown)
-        self._sched_wall = 0.0
-        self._monitor_wall = 0.0
-        self.phase_wall: dict = {}
+        # spans and counts of the current (or last) run; see run()
+        self._rec = tracing.Record()
+        self.timings: dict = {}
         # dependency-counter scheduling state (built in _prepare at run())
         self._seq: dict[str, int] = {}           # instance -> submission order
         self._seq_next = 0
@@ -592,6 +592,7 @@ class Engine:
             self._name_slots = ns
 
     def _start(self, task: TaskInstance, node_name: str):
+        self._rec.count("placements")
         na = self._na
         i = na.index[node_name]
         na.free_cores[i] -= task.req_cores
@@ -730,7 +731,7 @@ class Engine:
                                   task.run_id, task.node,
                                   self.t - task.start_t, usage,
                                   tenant=task.tenant))
-            self._monitor_wall += time.perf_counter() - t0
+            self._rec.count("monitor_s", time.perf_counter() - t0)
             if self._spec_on:
                 # the new trace only moves this (workflow, task)'s p95:
                 # refresh exactly the running slots that share the name
@@ -1308,9 +1309,30 @@ class Engine:
         call, possibly after a ``snapshot()``/``restore()`` round-trip in a
         different process; the pause never splits a floating-point task
         advance, so the resumed trace is bit-for-bit identical to an
-        uninterrupted run (pinned by tests/test_faults.py)."""
-        with np.errstate(divide="ignore"):
-            return self._run_loop(max_t, until)
+        uninterrupted run (pinned by tests/test_faults.py).
+
+        ``result["timings"]``, kept as ``self.timings``, is the run's
+        ``tracing.Record``: the span ``engine.run`` around the whole loop
+        (``run_s``); the loop's seconds in scheduling passes (``schedule_s``:
+        queue order and placement), in monitor ingestion (``monitor_s``) and
+        in the rest (``event_s``), added without a span per event; the
+        counts ``events`` (events the loop processed) and ``placements``;
+        and what the scheduler counted during the run (``counts``, e.g.
+        Tarema's ``label_computes`` and ``score_dispatches``)."""
+        rec = self._rec = tracing.Record()
+        rec.totals.update(schedule_s=0.0, monitor_s=0.0, events=0,
+                          placements=0)
+        counts = getattr(self.scheduler, "counts", {})
+        before = dict(counts)
+        with rec.span("engine.run"), np.errstate(divide="ignore"):
+            out = self._run_loop(max_t, until)
+        tot = rec.totals
+        tot["event_s"] = max(
+            tot["run_s"] - tot["schedule_s"] - tot["monitor_s"], 0.0)
+        for name, n in counts.items():
+            rec.count(name, n - before.get(name, 0))
+        self.timings = out["timings"] = rec.as_dict()
+        return out
 
     def _run_loop(self, max_t: float, until: Optional[float] = None) -> dict:
         # one blanket divide-only errstate for the whole loop (zero-rate
@@ -1319,8 +1341,7 @@ class Engine:
         # live as a guardrail (a NaN reaching scheduler/monitor/sizing math
         # is always a bug) — dead slots can't produce 0/0 because their
         # remaining-work rows are zeroed on release
-        t_run0 = time.perf_counter()
-        self._sched_wall = self._monitor_wall = 0.0   # per-run attribution
+        rec = self._rec
         self._prepare()
         paused = False
         while True:
@@ -1330,7 +1351,7 @@ class Engine:
             self._promote_ready()
             t0 = time.perf_counter()
             self._schedule()
-            self._sched_wall += time.perf_counter() - t0
+            rec.count("schedule_s", time.perf_counter() - t0)
             self._maybe_speculate()
             if not self.running:
                 if self._unfinished == 0:
@@ -1348,6 +1369,7 @@ class Engine:
                 else:
                     self.t = max(self.t, next_exo)
                     self._process_exo()
+                rec.count("events")
                 # uniform runaway guard: *every* time advance checks max_t
                 # (the arrival jump used to continue unchecked, and the
                 # exogenous checks were gated on a fault model being
@@ -1401,11 +1423,13 @@ class Engine:
                 self._advance_full(max(et - self.t, 0.0), n, tl)
                 self.t = et
                 self._process_exo()
+                rec.count("events")
                 if self.t > max_t:
                     raise RuntimeError("simulation exceeded max_t")
                 continue
             self._advance_full(dt, n, tl)
             self.t = float(t_next)
+            rec.count("events")
             if reap >= 0:              # timeout: reap the hung attempt
                 self._fault_retry(self._slot_tasks[reap], "timeout")
                 self._maybe_compact()
@@ -1457,14 +1481,6 @@ class Engine:
             self._maybe_compact()
             if self.t > max_t:
                 raise RuntimeError("simulation exceeded max_t")
-        # per-phase wall breakdown (scheduling = order + placement passes,
-        # monitor = TraceDB ingestion, event = everything else in the loop)
-        total = time.perf_counter() - t_run0
-        self.phase_wall = {
-            "schedule_s": self._sched_wall,
-            "monitor_s": self._monitor_wall,
-            "event_s": max(total - self._sched_wall - self._monitor_wall, 0.0),
-        }
         return {"makespan": self._max_end, "assignments": self.assignments,
                 "paused": paused}
 

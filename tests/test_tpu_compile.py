@@ -67,6 +67,20 @@ def test_grouping_program_compiles_with_the_kernel(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_phase_one_grouping_compiles_with_the_kernel(one_chip, monkeypatch):
+    """Phase 1 of a Tarema run groups its partition's profiles on the chip:
+    256 nodes of 6 features, every k that ``choose_k`` sweeps."""
+    from repro.core import clustering
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    x = _sds((256, 6), jnp.float32, one_chip)
+    key = _sds((), jax.random.key(0).dtype, one_chip)
+    for k in range(2, 7):
+        compiled = clustering._kmeans_pp.lower(x, k=k, key=key, iters=32,
+                                               use_kernel=True).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("sched", ["fair", "sjfn"])
 def test_f64_scan_compiles(one_chip, sched):
     """The ensemble bench's quick scale: 64 nodes x 500 instances x 16

@@ -73,3 +73,64 @@ def test_spans_lie_in_the_profiler_trace_on_the_callers_thread(tmp_path):
     (_, c0, c1), = [e for e in mine[0] if e[0] == "caller"]
     (_, s0, s1), = [e for e in mine[0] if e[0] == "entry.phase"]
     assert c0 <= s0 <= s1 <= c1
+
+
+def _tarema_engine(sched_name="tarema"):
+    from repro.core.monitor import TraceDB
+    from repro.core.scheduler import make_scheduler
+    from repro.workflow.cluster import cluster_555
+    from repro.workflow.engine import Engine, EngineConfig
+    from repro.workflow.nfcore import WORKFLOWS
+
+    specs = cluster_555()
+    eng = Engine(specs, make_scheduler(sched_name, specs, seed=0), TraceDB(),
+                 EngineConfig(seed=0))
+    for i, name in enumerate(("viralrecon", "eager")):
+        eng.submit(WORKFLOWS[name](), run_id=0, seed=i, prefix=name)
+    return eng
+
+
+def test_engine_run_record_splits_the_loop_and_counts_its_work():
+    eng = _tarema_engine()
+    t0 = time.perf_counter()
+    res = eng.run()
+    wall = time.perf_counter() - t0
+    t = res["timings"]
+    assert t is eng.timings
+    assert [s[0] for s in t["spans"]] == ["engine.run"]
+    assert 0 < t["run_s"] <= wall
+    assert min(t["schedule_s"], t["monitor_s"], t["event_s"]) > 0
+    assert t["schedule_s"] + t["monitor_s"] + t["event_s"] \
+        == pytest.approx(t["run_s"])
+    done = [a for a in eng.assignment_log if a.completed]
+    assert t["placements"] == len(done) == len(eng.all_tasks)
+    assert t["events"] >= len(done)
+    assert 0 < t["score_dispatches"] <= t["label_computes"] <= t["placements"]
+
+
+def test_engine_run_records_are_per_run():
+    """A paused run and its resumption each report their own work; a
+    scheduler that counts nothing adds no counts."""
+    eng = _tarema_engine("fair")
+    first = eng.run(until=300.0)
+    assert first["paused"]
+    second = eng.run()
+    assert not second["paused"] and second["timings"] is eng.timings
+    assert first["timings"]["placements"] > 0
+    assert first["timings"]["placements"] + second["timings"]["placements"] \
+        == len(eng.all_tasks)
+    assert "label_computes" not in second["timings"]
+
+
+def test_phase_one_record_nests_the_grouping():
+    from repro.core.scheduler import make_scheduler
+    from repro.workflow.cluster import cluster_555
+
+    sched = make_scheduler("tarema", cluster_555(), seed=0)
+    t = sched.timings
+    assert [s[0] for s in t["spans"]] == ["tarema.profile", "tarema.group",
+                                          "tarema.info"]
+    assert min(t["profile_s"], t["info_s"]) > 0
+    assert t["group_s"] >= t["grouping"]["kmeans_s"] > 0
+    assert t["grouping"]["kmeans_runs"] == 5 * 4     # k 2..6, 4 restarts
+    assert sched.counts == {"label_computes": 0, "score_dispatches": 0}
