@@ -81,7 +81,7 @@ from repro import tracing
 from repro.core.monitor import TraceDB
 from repro.core.scheduler import (FairScheduler, FillNodesScheduler,
                                   RoundRobinScheduler, SJFNScheduler)
-from repro.core.seeding import stable_seed
+from repro.core.seeding import pcg64_state, seed_sequence_state, stable_seed
 from repro.workflow.dag import WorkflowSpec, instantiate
 from repro.workflow.engine import Engine, EngineConfig, _NodeArrays
 
@@ -161,10 +161,12 @@ def _reset_scheduler_for_replica(sched) -> None:
 
 # ---------------------------------------------------------------- topology
 class _Topology:
-    """Static (replica-independent) arrays of the instantiated DAG."""
+    """Static (replica-independent) arrays of the instantiated DAG.
+    ``record`` is the call's ``tracing.Record``, in which
+    :meth:`replica_work` times its draws as the span ``ensemble.draws``."""
 
     def __init__(self, specs, submissions, scheduler, config, n_replicas,
-                 seed_stride):
+                 seed_stride, record: Optional[tracing.Record] = None):
         if not submissions:
             raise ValueError("ensemble needs at least one Submission")
         if n_replicas < 1:
@@ -194,6 +196,7 @@ class _Topology:
         self.kind = type(scheduler).name
         self.n_replicas = int(n_replicas)
         self.seed_stride = int(seed_stride)
+        self.record = record if record is not None else tracing.Record()
         self.submissions = list(submissions)
 
         # -- node statics (via _NodeArrays so derived columns — mem_static,
@@ -338,18 +341,44 @@ class _Topology:
     def replica_work(self) -> np.ndarray:
         """[R, T, 3] f64 work arrays, bit-identical to ``instantiate`` with
         seed ``sub.seed + r * seed_stride``: numpy's vectorized lognormal
-        yields the same stream as n sequential scalar draws."""
-        R = self.n_replicas
-        out = np.zeros((R, self.T, 3), np.float64)
-        for r in range(R):
-            for (lo, hi), sub in zip(self._sub_slices, self.submissions):
-                rng = np.random.default_rng(
-                    (stable_seed(sub.spec.name),
-                     sub.seed + r * self.seed_stride, sub.run_id))
-                run_scale = float(rng.lognormal(0.0, 0.05)) * sub.input_scale
-                scales = rng.lognormal(0.0, 0.35, hi - lo) * run_scale
-                out[r, lo:hi] = self.base_work[lo:hi] * scales[:, None]
+        yields the same stream as n sequential scalar draws.  Every
+        (replica, submission) stream is
+        ``default_rng((stable_seed(name), seed, run_id))``'s, seeded for all
+        streams at once and drawn from one reused generator."""
+        R, subs = self.n_replicas, self.submissions
+        S = len(subs)
+        with self.record.span("ensemble.draws"):
+            names = np.array([stable_seed(s.spec.name) for s in subs])
+            run_ids = np.array([s.run_id for s in subs])
+            words = seed_sequence_state(
+                np.tile(names, R),
+                _replica_seeds(subs, R, self.seed_stride).ravel(),
+                np.tile(run_ids, R)).tolist()
+            bitgen = np.random.PCG64(0)
+            rng = np.random.Generator(bitgen)
+            out = np.zeros((R, self.T, 3), np.float64)
+            for r in range(R):
+                for j, ((lo, hi), sub) in enumerate(zip(self._sub_slices,
+                                                        subs)):
+                    bitgen.state = pcg64_state(words[r * S + j])
+                    run_scale = (float(rng.lognormal(0.0, 0.05))
+                                 * sub.input_scale)
+                    scales = rng.lognormal(0.0, 0.35, hi - lo) * run_scale
+                    out[r, lo:hi] = self.base_work[lo:hi] * scales[:, None]
         return out
+
+
+def _replica_seeds(subs, n_replicas: int, stride: int) -> np.ndarray:
+    """[R, S] seeds ``sub.seed + r * stride``: uint64 where every one fits
+    (wrapping arithmetic is then exact), Python ints otherwise."""
+    base = [s.seed for s in subs]
+    ends = base + [b + (n_replicas - 1) * stride for b in base]
+    if 0 <= min(ends) and max(ends) < 1 << 64:
+        return (np.array(base, np.uint64)[None, :]
+                + np.arange(n_replicas, dtype=np.uint64)[:, None]
+                * np.uint64(stride % (1 << 64)))
+    return (np.array(base, object)[None, :]
+            + np.arange(n_replicas, dtype=object)[:, None] * stride)
 
 
 # ------------------------------------------------------------------- scan
@@ -895,26 +924,27 @@ def run_ensemble(specs, submissions, scheduler, n_replicas, *,
     and does no tracing, lowering or compiling; any other call compiles
     its program once.  The program is then run once.  ``timings`` is the
     call's ``tracing.Record``: the seconds of the spans ``ensemble.build``
-    (topology, work draws, program lookup and uploads),
+    (topology, work draws, program lookup and uploads) and
+    ``ensemble.draws`` within it (the work draws),
     ``ensemble.compile`` (lowering and compiling, on a miss only),
     ``ensemble.run`` (the run, to ``block_until_ready``),
     ``ensemble.fetch`` (copies to the host and the result) and
     ``ensemble.release`` (dropping the call's device buffers; the program
-    stays cached) as ``build_s``, ``compile_s``, ``run_s``, ``fetch_s``
-    and ``release_s``; the counts ``compiles`` and ``program_hits`` (one
-    of them 1, the other 0), and the scan's ``n_steps``.  Two more count
-    the scan's work: ``key_rebuilds``, the steps on which the extraction
-    keys were built, is ``n_steps``, since every signature builds them
-    afresh on every step; ``place_iters``, counted inside the scan, is the
-    placement ``while_loop``'s iterations summed over the steps (an
-    iteration serves every replica at once)."""
+    stays cached) as ``build_s``, ``draws_s``, ``compile_s``, ``run_s``,
+    ``fetch_s`` and ``release_s``; the counts ``compiles`` and
+    ``program_hits`` (one of them 1, the other 0), and the scan's
+    ``n_steps``.  Two more count the scan's work: ``key_rebuilds``, the
+    steps on which the extraction keys were built, is ``n_steps``, since
+    every signature builds them afresh on every step; ``place_iters``,
+    counted inside the scan, is the placement ``while_loop``'s iterations
+    summed over the steps (an iteration serves every replica at once)."""
     import jax
 
     rec = tracing.Record()
     with jax.enable_x64(True):
         with rec.span("ensemble.build"):
             top = _Topology(specs, submissions, scheduler, config,
-                            n_replicas, seed_stride)
+                            n_replicas, seed_stride, rec)
             scan, args = _build_scan(top)
         compiled = _PROGRAMS.compiled(scan)
         if compiled is None:

@@ -241,12 +241,13 @@ def test_run_ensemble_compiles_once_and_runs_once(monkeypatch):
     t = res.timings
     assert t["compiles"] == 1 and t["n_steps"] > 0
     assert "compile_run_s" not in t
-    for key in ("build_s", "compile_s", "run_s", "fetch_s", "release_s"):
+    for key in ("build_s", "draws_s", "compile_s", "run_s", "fetch_s",
+                "release_s"):
         assert t[key] > 0.0
     assert [(name, parent) for name, _, _, parent in t["spans"]] == [
-        ("ensemble.build", None), ("ensemble.compile", None),
-        ("ensemble.run", None), ("ensemble.fetch", None),
-        ("ensemble.release", None)]
+        ("ensemble.build", None), ("ensemble.draws", 0),
+        ("ensemble.compile", None), ("ensemble.run", None),
+        ("ensemble.fetch", None), ("ensemble.release", None)]
 
 
 def test_scan_module_is_named_jit_scan():
@@ -418,8 +419,10 @@ def test_repeat_topology_reuses_the_compiled_program(sched_name):
     assert (warm.timings["compiles"], warm.timings["program_hits"]) == (0, 1)
     assert events == []
     assert "compile_s" not in warm.timings
-    assert [name for name, *_ in warm.timings["spans"]] == [
-        "ensemble.build", "ensemble.run", "ensemble.fetch", "ensemble.release"]
+    assert [(name, parent) for name, _, _, parent in warm.timings["spans"]] \
+        == [("ensemble.build", None), ("ensemble.draws", 0),
+            ("ensemble.run", None), ("ensemble.fetch", None),
+            ("ensemble.release", None)]
     assert_equivalent(first, _oracle(specs, subs(1), sched_name))
     assert_equivalent(warm, _oracle(specs, subs(2), sched_name))
     ensemble._PROGRAMS.clear()
